@@ -20,8 +20,6 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import contextlib
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,13 +33,8 @@ from repro.roofline.hlo import collective_bytes
 
 
 def main():
-    # axis_types / set_mesh only exist on newer jax; all shardings below
-    # are explicit NamedShardings, so older versions run without them
-    if hasattr(jax.sharding, "AxisType"):
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
-    else:
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     cfg = get_config("qwen3-4b").reduced(d_model=64, n_layers=2)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -54,8 +47,7 @@ def main():
 
     mcfg = MezoConfig(eps=1e-2, lr=1e-2, n_directions=2)  # 1 per pod
 
-    set_mesh = getattr(jax, "set_mesh", None)
-    with (set_mesh(mesh) if set_mesh else contextlib.nullcontext()):
+    with jax.set_mesh(mesh):
         strat = get_strategy("mezo-parallel")
         lowered = strat.lower(model.loss, strat.init_state(params, mcfg),
                               batch, jnp.uint32(0), mcfg, None)

@@ -10,14 +10,10 @@ the slot's pages by the caller before the read (exactly the verify
 kernel's contract), so the kernel is pure page reads and no dense B=1
 prompt cache ever exists.
 
-Where flash_verify spends a grid dimension per window offset (right for
-the W = k+1 <= ~5 speculative windows), this kernel keeps the whole
-C-token chunk resident in VMEM per (slot, kv head) and sweeps the pages
-once: grid (B, KV, n_live), q block (1, 1, C, G, hd), scores
-(C*G, page_size) per tile with per-row causal limits, online-softmax
-partials (acc, m, l) sized (C*G, ...) in VMEM scratch. One scratch
-lifetime per (slot, kv head) instead of per (slot, kv head, offset) --
-C times fewer page sweeps than routing a chunk through the verify grid.
+The chunk's C*G query rows per KV head stay resident in VMEM through
+one page sweep (flash_decode's shared ``paged_attention``): scores
+(C*G, page_size) per head and page with per-row causal limits,
+online-softmax partials (acc, m, l) sized (C*G, ...) in VMEM scratch.
 
 Layout: q (B, C, H, hd) -- C chunk tokens per slot; k/v pools
 (n_pages, page_size, KV, hd); pages (B, n_live) physical page ids;
@@ -34,57 +30,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_decode import check_head_dim
-
-_NEG_INF = -1e30
-
-
-def _prefill_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                    acc_ref, m_ref, l_ref, *, ps, n_live, c, g, scale):
-    bi = pl.program_id(0)
-    pp = pl.program_id(2)
-
-    @pl.when(pp == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # the page is live iff the chunk's LAST row can see it; per-row
-    # masking below handles earlier rows' tighter causal limits
-    pos0 = pos_ref[bi]
-    live = pp * ps <= pos0 + (c - 1)
-
-    @pl.when(live)
-    def _():
-        q = q_ref[0, 0].astype(jnp.float32).reshape(c * g, -1) * scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (ps, hd)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        k_pos = pp * ps + jax.lax.broadcasted_iota(
-            jnp.int32, (1, ps), 1)                           # (1, ps)
-        # row r of the (C*G)-row tile is chunk offset r // g: it attends
-        # through pos0 + r//g (earlier chunks + causal inside the chunk)
-        q_pos = pos0 + jax.lax.broadcasted_iota(
-            jnp.int32, (c * g, 1), 0) // g                   # (C*G, 1)
-        s = jnp.where(k_pos <= q_pos, s, _NEG_INF)           # (C*G, ps)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jnp.dot(p, v, preferred_element_type=jnp.float32))
-        m_ref[...] = m_new
-
-    @pl.when(pp == n_live - 1)
-    def _():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_ref[...] / denom).reshape(
-            o_ref.shape[2:]).astype(o_ref.dtype)
+from repro.kernels.flash_decode import check_head_dim, paged_attention
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -98,43 +45,9 @@ def flash_prefill(q, k_pages, v_pages, pages, pos, *,
     entries) is masked out. The table must cover pos + C - 1 -- the
     admission reservation guarantees the pages exist.
     """
-    b, c, h, hd = q.shape
-    _, ps, kvh, _ = k_pages.shape
-    g = h // kvh
-    n_live = pages.shape[1]
-    check_head_dim(hd, interpret=interpret, kernel="flash_prefill")
-    qg = q.reshape(b, c, kvh, g, hd).transpose(0, 2, 1, 3, 4)
-
-    def qmap(bi, kv, pp, pages_ref, pos_ref):
-        return (bi, kv, 0, 0, 0)
-
-    def kvmap(bi, kv, pp, pages_ref, pos_ref):
-        return (pages_ref[bi, pp], 0, kv, 0)
-
-    kern = functools.partial(_prefill_kernel, ps=ps, n_live=n_live,
-                             c=c, g=g, scale=1.0 / float(hd) ** 0.5)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                     # pages, pos
-        grid=(b, kvh, n_live),
-        in_specs=[
-            pl.BlockSpec((1, 1, c, g, hd), qmap),
-            pl.BlockSpec((1, ps, 1, hd), kvmap),
-            pl.BlockSpec((1, ps, 1, hd), kvmap),
-        ],
-        out_specs=pl.BlockSpec((1, 1, c, g, hd), qmap),
-        scratch_shapes=[
-            pltpu.VMEM((c * g, hd), jnp.float32),
-            pltpu.VMEM((c * g,), jnp.float32),
-            pltpu.VMEM((c * g,), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, c, g, hd), q.dtype),
-        interpret=interpret,
-    )(pages.astype(jnp.int32), pos.astype(jnp.int32), qg, k_pages, v_pages)
-    return out.transpose(0, 2, 1, 3, 4).reshape(b, c, h, hd)
+    check_head_dim(q.shape[-1], interpret=interpret, kernel="flash_prefill")
+    return paged_attention(q, k_pages, v_pages, pages, pos,
+                           interpret=interpret)
 
 
 def prefill_attn_ref(q, k_pages, v_pages, pages, pos):

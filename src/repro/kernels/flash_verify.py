@@ -12,10 +12,10 @@ attention pass, and speculation adds zero KV HBM.
 
 Layout: q (B, W, H, hd) -- W candidate tokens per slot; k/v pools
 (n_pages, page_size, KV, hd); pages (B, n_live) physical page ids;
-pos (B,) each slot's first window position. Grid (B, KV, W, n_live),
-pages innermost so the online-softmax partials (acc, m, l) in VMEM
-scratch reduce over pages exactly as flash_decode does -- one scratch
-lifetime per (slot, kv head, window offset).
+pos (B,) each slot's first window position. The window's W*G query rows
+per KV head ride flash_decode's shared page sweep
+(``paged_attention``), so the online-softmax partials reduce over pages
+exactly as flash_decode's do.
 
 ``verify_attn_ref`` is the pure-jnp oracle and the non-TPU hot path; at
 W=1 it degenerates to the same math as ``paged_attn_ref``.
@@ -27,53 +27,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_decode import check_head_dim
-
-_NEG_INF = -1e30
-
-
-def _verify_kernel(pages_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, ps, n_live, scale):
-    bi = pl.program_id(0)
-    wi = pl.program_id(2)
-    pp = pl.program_id(3)
-
-    @pl.when(pp == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # window offset w attends through position pos + w: the draft tokens
-    # earlier in the window are visible (causal inside the window), the
-    # later ones and the slot's dead tail are not
-    pos = pos_ref[bi] + wi
-    live = pp * ps <= pos
-
-    @pl.when(live)
-    def _():
-        q = q_ref[0, 0, 0].astype(jnp.float32) * scale       # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (ps, hd)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        k_pos = pp * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        s = jnp.where(k_pos <= pos, s, _NEG_INF)             # (G, ps)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                        + jnp.dot(p, v, preferred_element_type=jnp.float32))
-        m_ref[...] = m_new
-
-    @pl.when(pp == n_live - 1)
-    def _():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+from repro.kernels.flash_decode import check_head_dim, paged_attention
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -86,43 +41,9 @@ def flash_verify(q, k_pages, v_pages, pages, pos, *,
     later (the rest of the window, the dead tail, trash-page table
     entries) is masked out.
     """
-    b, w, h, hd = q.shape
-    _, ps, kvh, _ = k_pages.shape
-    g = h // kvh
-    n_live = pages.shape[1]
-    check_head_dim(hd, interpret=interpret, kernel="flash_verify")
-    qg = q.reshape(b, w, kvh, g, hd).transpose(0, 2, 1, 3, 4)
-
-    def qmap(bi, kv, wi, pp, pages_ref, pos_ref):
-        return (bi, kv, wi, 0, 0)
-
-    def kvmap(bi, kv, wi, pp, pages_ref, pos_ref):
-        return (pages_ref[bi, pp], 0, kv, 0)
-
-    kern = functools.partial(_verify_kernel, ps=ps, n_live=n_live,
-                             scale=1.0 / float(hd) ** 0.5)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                     # pages, pos
-        grid=(b, kvh, w, n_live),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, g, hd), qmap),
-            pl.BlockSpec((1, ps, 1, hd), kvmap),
-            pl.BlockSpec((1, ps, 1, hd), kvmap),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, g, hd), qmap),
-        scratch_shapes=[
-            pltpu.VMEM((g, hd), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, w, g, hd), q.dtype),
-        interpret=interpret,
-    )(pages.astype(jnp.int32), pos.astype(jnp.int32), qg, k_pages, v_pages)
-    return out.transpose(0, 2, 1, 3, 4).reshape(b, w, h, hd)
+    check_head_dim(q.shape[-1], interpret=interpret, kernel="flash_verify")
+    return paged_attention(q, k_pages, v_pages, pages, pos,
+                           interpret=interpret)
 
 
 def verify_attn_ref(q, k_pages, v_pages, pages, pos):

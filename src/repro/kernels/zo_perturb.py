@@ -51,6 +51,10 @@ def _avalanche(x):
     return x
 
 
+def _to_f32(x):
+    return x.astype(jnp.int32).astype(jnp.float32)
+
+
 def _tile_z(seed, salt, shape, row0, col0, dist: str,
             prime_offset: int = 0, prehashed: bool = False):
     """z tile of ``shape`` at absolute offset (row0, col0), f32.
@@ -66,12 +70,14 @@ def _tile_z(seed, salt, shape, row0, col0, dist: str,
     ci = jax.lax.broadcasted_iota(_U32, shape, 1) + jnp.asarray(col0, _U32)
     h = _avalanche(h ^ (ri * _U32(_DIM_PRIMES[prime_offset])))
     h = _avalanche(h ^ (ci * _U32(_DIM_PRIMES[prime_offset + 1])))
+    # Mosaic has no uint32 -> f32 cast; going through int32 is exact here
+    # (h >> 31 is 0/1, h >> 8 < 2**24), so tiles stay bit-equal to core.rng
     if dist == "rademacher":
-        return 1.0 - 2.0 * (h >> 31).astype(jnp.float32)
+        return 1.0 - 2.0 * _to_f32(h >> 31)
     # gaussian (Box-Muller)
     h2 = _avalanche(h ^ _U32(0x68E31DA4))
-    u1 = ((h >> 8).astype(jnp.float32) + 1.0) * (1.0 / 16777216.0)
-    u2 = (h2 >> 8).astype(jnp.float32) * (1.0 / 16777216.0)
+    u1 = (_to_f32(h >> 8) + 1.0) * (1.0 / 16777216.0)
+    u2 = _to_f32(h2 >> 8) * (1.0 / 16777216.0)
     return jnp.sqrt(-2.0 * jnp.log(u1)) * jnp.cos(6.283185307179586 * u2)
 
 
@@ -79,12 +85,14 @@ def _tile_z(seed, salt, shape, row0, col0, dist: str,
 # W + coeff * z
 
 
-def _pick(dim: int, want: int) -> int:
-    """Largest block size <= want that divides dim (prefers lane-aligned)."""
-    b = min(want, dim)
-    while dim % b:
-        b -= 1
-    return b
+def _pick(dim: int, want: int, align: int) -> int:
+    """Largest block size <= want that divides dim and is a multiple of
+    ``align`` (8 for a sublane axis, 128 for a lane axis), else the whole
+    dim: the two block shapes the TPU's tiling accepts."""
+    for b in range(min(want, dim) // align * align, 0, -align):
+        if dim % b == 0:
+            return b
+    return dim
 
 
 def _zo_add_kernel(seed_ref, coeff_ref, w_ref, o_ref, *, salt, bm, bn, dist,
@@ -121,7 +129,7 @@ def zo_add(w, seed, salt: int, coeff, dist: str = "rademacher",
     to ~1/4: the int8 values plus an (N,) scale vector.
     """
     m, n = w.shape
-    bm, bn = _pick(m, block[0]), _pick(n, block[1])
+    bm, bn = _pick(m, block[0], 8), _pick(n, block[1], 128)
     grid = (m // bm, n // bn)
     seed = jnp.asarray(seed, _U32).reshape(1)
     coeff = jnp.asarray(coeff, jnp.float32).reshape(1)
@@ -188,7 +196,7 @@ def zo_add_users(w, seeds, salt: int, coeffs, dist: str = "rademacher",
     sit in SMEM, indexed by ``program_id(0)``.
     """
     u, m, n = w.shape
-    bm, bn = _pick(m, block[0]), _pick(n, block[1])
+    bm, bn = _pick(m, block[0], 8), _pick(n, block[1], 128)
     seeds = jnp.asarray(seeds, _U32).reshape(u)
     coeffs = jnp.asarray(coeffs, jnp.float32).reshape(u)
     return pl.pallas_call(
@@ -280,7 +288,8 @@ def zo_matmul(x, w, seed, salt: int, coeff, dist: str = "rademacher",
     m, k = x.shape
     k2, n = w.shape
     assert k == k2
-    bm, bk, bn = _pick(m, blocks[0]), _pick(k, blocks[1]), _pick(n, blocks[2])
+    bm, bk, bn = (_pick(m, blocks[0], 8), _pick(k, blocks[1], 128),
+                  _pick(n, blocks[2], 128))
     grid = (m // bm, n // bn, k // bk)
     seed = jnp.asarray(seed, _U32).reshape(1)
     coeff = jnp.asarray(coeff, jnp.float32).reshape(1)
@@ -397,7 +406,8 @@ def zo_matmul_users(x, w, seeds, salt: int, coeffs,
     u, m, k = x.shape
     k2, n = w.shape
     assert k == k2
-    bm, bk, bn = _pick(m, blocks[0]), _pick(k, blocks[1]), _pick(n, blocks[2])
+    bm, bk, bn = (_pick(m, blocks[0], 8), _pick(k, blocks[1], 128),
+                  _pick(n, blocks[2], 128))
     grid = (u, m // bm, n // bn, k // bk)
     seeds = jnp.asarray(seeds, _U32).reshape(u)
     coeffs = jnp.asarray(coeffs, jnp.float32).reshape(u)

@@ -23,6 +23,7 @@ import numpy as np
 from repro.checkpoint import store
 from repro.configs import ALL_ARCHS, get_config
 from repro.core import MezoConfig
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.serve import (AdapterStore, Request, ServeEngine, sample_topk,
                          step_keys)
@@ -76,7 +77,9 @@ FAMILY_ARCHS = {
 }
 
 
-def main():
+def main(argv=None):
+    """Run the CLI on ``argv`` (default ``sys.argv[1:]``); returns the
+    engine and its rid-sorted completions."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma-2b", choices=ALL_ARCHS)
     ap.add_argument("--family", default=None, choices=sorted(FAMILY_ARCHS),
@@ -132,7 +135,8 @@ def main():
                          "whole-prompt admission stall; greedy output is "
                          "bit-identical to whole-prompt prefill and "
                          "composes with --spec-k")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.family:
         args.arch = FAMILY_ARCHS[args.family]
@@ -203,6 +207,7 @@ def main():
           f"tok/s | decode {st.decode_tps:.0f} tok/s | "
           f"adapter materializations: {adapters.stats['misses']} "
           f"(hits {adapters.stats['hits']})" + lat_note + paged_note)
+    return engine, completions
 
 
 if __name__ == "__main__":

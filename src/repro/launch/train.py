@@ -29,6 +29,7 @@ from repro.core.engine import (estimator_names, strategy_names,
                                update_rule_names)
 from repro.core.mezo import MezoConfig
 from repro.data.synthetic import lm_batches, sst2_batches
+from repro.launch import compile_cache
 from repro.optim.adam import AdamConfig
 from repro.runtime.trainer import Trainer, TrainerConfig
 
@@ -131,8 +132,11 @@ def build_argparser() -> argparse.ArgumentParser:
     return ap
 
 
-def main():
-    args = build_argparser().parse_args()
+def main(argv=None) -> Trainer:
+    """Run the CLI on ``argv`` (default ``sys.argv[1:]``); returns the
+    finished Trainer, whose ``losses`` hold every step's loss."""
+    args = build_argparser().parse_args(argv)
+    compile_cache.enable()
 
     tr = make_trainer(args)
     params = tr.train()
@@ -144,6 +148,7 @@ def main():
                        "losses": tr.losses}, f)
     print(f"[train] done: loss {tr.losses[0]:.4f} -> {tr.losses[-1]:.4f} "
           f"({len(tr.losses)} steps)")
+    return tr
 
 
 if __name__ == "__main__":

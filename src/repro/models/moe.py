@@ -67,13 +67,8 @@ def capacity(n_tokens: int, cfg) -> int:
 
 
 def _ambient_mesh():
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return None
-    if am is None or getattr(am, "empty", True):
-        return None
-    return am
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.empty else am
 
 
 def moe_apply_ep(cfg, p, x):

@@ -87,13 +87,10 @@ def maybe_shard(x, *spec):
     """with_sharding_constraint iff an ambient mesh with the named axes is
     active (jax.set_mesh). No-op in mesh-less CPU smoke tests, so model
     code can annotate activations unconditionally."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-    except Exception:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return x
-    if am is None or getattr(am, "empty", True):
-        return x
-    names = set(am.axis_names or ())
+    names = set(am.axis_names)
     if any(a not in names for a in jax.tree.leaves(list(spec))
            if isinstance(a, str)):
         return x

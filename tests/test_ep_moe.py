@@ -8,7 +8,6 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 SCRIPT = r"""
@@ -67,10 +66,6 @@ print("EP_MOE_OK")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not (hasattr(jax.sharding, "AxisType") and hasattr(jax, "set_mesh")),
-    reason="needs jax explicit-sharding API (jax.sharding.AxisType / "
-           "jax.set_mesh, jax >= 0.6)")
 def test_ep_moe_subprocess():
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
