@@ -237,24 +237,20 @@ def kernels_phase(seed: int = 0) -> None:
                                  f"{KERNEL_TOL} or no tpu_custom_call")
 
 
-class CompileClock:
-    """Seconds JAX spends in backend compiles (or persistent-cache
-    loads), fed by ``jax.monitoring``'s duration events."""
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        self.seconds = 0.0
-
-    def __call__(self, event: str, secs: float, **_) -> None:
-        if event == self.EVENT:
-            self.seconds += secs
+def compile_totals():
+    """Backend compiles (or persistent-cache loads) so far and their
+    seconds, from the program's own counter."""
+    from repro import obs
+    per_fun = obs.compiles().values()
+    return sum(n for n, _ in per_fun), sum(s for _, s in per_fun)
 
 
-def timed(clock: CompileClock, name: str, fn, *args):
-    c0, t0 = clock.seconds, time.perf_counter()
+def timed(name: str, fn, *args):
+    (n0, c0), t0 = compile_totals(), time.perf_counter()
     out = fn(*args)
-    log(f"{name}: wall {time.perf_counter() - t0!r}s, of which backend "
-        f"compile or cache load {clock.seconds - c0!r}s | peak HBM "
+    n1, c1 = compile_totals()
+    log(f"{name}: wall {time.perf_counter() - t0!r}s, of which {n1 - n0} "
+        f"backend compiles or cache loads {c1 - c0!r}s | peak HBM "
         f"{peak_hbm()} B")
     return out
 
@@ -272,18 +268,16 @@ def main() -> int:
 
     log(f"device {dev.device_kind!r} x{len(jax.devices())}, compile cache "
         f"{compile_cache.enable()}")
-    clock = CompileClock()
-    jax.monitoring.register_event_duration_secs_listener(clock)
     ckpt = os.path.join(WORK, "ckpt")
 
-    t = timed(clock, "train", train_phase, ckpt)
+    t = timed("train", train_phase, ckpt)
     log(f"train: losses {t['losses']}")
-    s = timed(clock, "serve", serve_phase, ckpt)
+    s = timed("serve", serve_phase, ckpt)
     log(f"serve: {len(s['completions'])} requests | engine prefill "
         f"{s['prefill_s']!r}s, decode {s['decode_s']!r}s (first calls "
         f"compile), adapter replay {s['adapter_materialize_s']!r}s | spec "
         f"acceptance {s['spec_accept_rate']!r}")
-    timed(clock, "kernels", kernels_phase)
+    timed("kernels", kernels_phase)
 
     programs = {**t["kernels"], **s["kernels"]}
     log(f"tpu_custom_call in {programs}")

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import store
 from repro.checkpoint.replay_log import ReplayLog, replay_into
 from repro.core.engine import SGD, TrainState, UpdateRule
@@ -63,11 +64,13 @@ class CheckpointManager:
         ``direction_mask`` is the step's straggler mask, logged so replay
         renormalizes over the same survivors."""
         if self.log is not None and aux is not None:
-            self.log.append(step, aux.seed, aux.gs, self.cfg.lr,
-                            self.cfg.eps, mask=direction_mask)
+            with obs.span("ckpt.append", step=step):    # waits on gs
+                self.log.append(step, aux.seed, aux.gs, self.cfg.lr,
+                                self.cfg.eps, mask=direction_mask)
         if step % self.snapshot_every == 0:
-            store.save_params(self.dir, step, state)
-            self._gc()
+            with obs.span("ckpt.snapshot", step=step):
+                store.save_params(self.dir, step, state)
+                self._gc()
 
     def _gc(self):
         steps = sorted(int(d.split("_")[1]) for d in os.listdir(self.dir)

@@ -57,6 +57,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import rng as zrng
 from repro.core.perturb import add_scaled_z
 from repro.core.perturb_ctx import PerturbCtx
@@ -253,10 +254,12 @@ def _eval_walk(loss_fn: LossFn, params: PyTree, batch: Any, seed,
     def one_dir(p, k):
         s = zrng.fold_seed(seed, k)
         p = add_scaled_z(p, s, eps, dist=cfg.dist, use_kernel=cfg.use_kernel)
-        lp = loss_fn(p, batch)
+        with jax.named_scope(obs.FORWARD):
+            lp = loss_fn(p, batch)
         p = add_scaled_z(p, s, -2.0 * eps, dist=cfg.dist,
                          use_kernel=cfg.use_kernel)
-        lm = loss_fn(p, batch)
+        with jax.named_scope(obs.FORWARD):
+            lm = loss_fn(p, batch)
         # restore to base point for the next direction
         p = add_scaled_z(p, s, eps, dist=cfg.dist, use_kernel=cfg.use_kernel)
         return p, ((lp - lm) / (2.0 * eps), 0.5 * (lp + lm))
@@ -275,8 +278,12 @@ def _eval_vmapdir(loss_fn: LossFn, params: PyTree, batch: Any, seed,
 
     def eval_dir(k):
         s = zrng.fold_seed(seed, k)
-        lp = loss_fn(add_scaled_z(params, s, eps, dist=cfg.dist), batch)
-        lm = loss_fn(add_scaled_z(params, s, -eps, dist=cfg.dist), batch)
+        pp = add_scaled_z(params, s, eps, dist=cfg.dist)
+        with jax.named_scope(obs.FORWARD):
+            lp = loss_fn(pp, batch)
+        pm = add_scaled_z(params, s, -eps, dist=cfg.dist)
+        with jax.named_scope(obs.FORWARD):
+            lm = loss_fn(pm, batch)
         return (lp - lm) / (2.0 * eps), 0.5 * (lp + lm)
 
     gs, ls = jax.vmap(eval_dir)(
@@ -296,9 +303,11 @@ def _eval_fused(loss_fn: LossFn, params: PyTree, batch: Any, seed,
         s = zrng.fold_seed(seed, k)
         ctx = PerturbCtx(seed=s, coeff=eps, dist=cfg.dist,
                          use_kernel=cfg.use_kernel)
-        lp = loss_fn(params, batch, perturb=ctx)
-        lm = loss_fn(params, batch,
-                     perturb=dataclasses.replace(ctx, coeff=-eps))
+        with jax.named_scope(obs.FORWARD):
+            lp = loss_fn(params, batch, perturb=ctx)
+        with jax.named_scope(obs.FORWARD):
+            lm = loss_fn(params, batch,
+                         perturb=dataclasses.replace(ctx, coeff=-eps))
         return None, ((lp - lm) / (2.0 * eps), 0.5 * (lp + lm))
 
     _, (gs, ls) = jax.lax.scan(one_dir, None,
@@ -431,8 +440,9 @@ def _step_body(strategy: "ZOStrategy", loss_fn: LossFn, state: TrainState,
     seed = jnp.asarray(seed, jnp.uint32)
     params, gs, ls = strategy.estimator.eval_fn(
         loss_fn, state.params, batch, seed, cfg, eps=eps)
-    params, opt = strategy.update.update_fn(
-        params, state.opt, seed, gs, direction_mask, cfg, lr=lr)
+    with jax.named_scope(obs.UPDATE):
+        params, opt = strategy.update.update_fn(
+            params, state.opt, seed, gs, direction_mask, cfg, lr=lr)
     aux = MezoAux(loss=ls.mean(), gs=gs, seed=seed,
                   grad_norm_est=jnp.abs(gs).mean())
     return TrainState(params=params, step=state.step + jnp.uint32(1),
@@ -517,12 +527,15 @@ class ZOStrategy:
                           opt=self.update.init_fn(cfg))
 
     def step(self, loss_fn: LossFn, state: TrainState, batch: Any, seed,
-             cfg: MezoConfig, direction_mask=None
+             cfg: MezoConfig, direction_mask=None, step: Optional[int] = None
              ) -> Tuple[TrainState, MezoAux]:
+        """One step; ``step`` (the caller's step number) only labels the
+        host span."""
         fn = _jit_step_donate if self.estimator.donate else _jit_step
-        return fn(self, loss_fn, state, batch,
-                  jnp.asarray(seed, jnp.uint32), cfg, direction_mask,
-                  jnp.float32(cfg.eps), jnp.float32(cfg.lr))
+        with obs.span("zo.step", step=step):
+            return fn(self, loss_fn, state, batch,
+                      jnp.asarray(seed, jnp.uint32), cfg, direction_mask,
+                      jnp.float32(cfg.eps), jnp.float32(cfg.lr))
 
     def lower(self, loss_fn: LossFn, state: TrainState, batch: Any, seed,
               cfg: MezoConfig, direction_mask=None):

@@ -42,6 +42,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import rng as zrng
 from repro.core.perturb import _path_str, is_perturbable, kernel_aligned
 from repro.optim.quant import is_quantized, take_rows, take_rows_f32
@@ -163,10 +164,15 @@ class PerturbCtx:
         leaves VMEM); everything else falls back to a transient jnp
         materialization with identical values (ref.zo_matmul_ref semantics,
         cast back to the weight dtype like ``add_scaled_z`` so the f32 path
-        is bit-exact with the sequential strategies).
+        is bit-exact with the sequential strategies). Either way the ops
+        run under the device scope ``zo_matmul.<projection path>``.
         """
-        if self.batched:
-            return self._matmul_users(x, w, name)
+        with jax.named_scope(obs.MATMUL + (self.prefix or name)):
+            if self.batched:
+                return self._matmul_users(x, w, name)
+            return self._matmul_one(x, w, name)
+
+    def _matmul_one(self, x, w, name: str):
         path, base, off = self._leaf(name)
         if not is_perturbable(path) or \
                 not jnp.issubdtype(w.dtype, jnp.floating):
@@ -221,7 +227,7 @@ class PerturbCtx:
         w_ax = None if (shared and not is_quantized(w)) \
             else self._user_axes(w)
         return jax.vmap(
-            lambda s, c, xu, wu: self._lane(s, c).matmul(xu, wu, name),
+            lambda s, c, xu, wu: self._lane(s, c)._matmul_one(xu, wu, name),
             in_axes=(0, 0, 0, w_ax))(seeds, coeffs, x, w)
 
     def take(self, name: str, table, ids):

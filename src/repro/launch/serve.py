@@ -199,8 +199,11 @@ def main(argv=None):
     if engine.prefill_chunk:
         paged_note += f" | chunked prefill C={engine.prefill_chunk}"
     n_done = max(len(completions), 1)
+    gaps = [b - a for c in completions
+            for a, b in zip(c.token_ts, c.token_ts[1:])]
     lat_note = (f" | ttft avg {st.ttft_s / n_done * 1e3:.0f}ms "
-                f"(queue {st.queue_wait_s / n_done * 1e3:.0f}ms) | "
+                f"(queue {st.queue_wait_s / n_done * 1e3:.0f}ms), token "
+                f"gap avg {sum(gaps) / max(len(gaps), 1) * 1e3:.1f}ms | "
                 f"decode stall {st.decode_stall_s:.2f} slot-s")
     print(f"[serve] {args.requests} reqs x ({args.prompt_len} prompt + "
           f"{args.gen} gen) in {dt:.2f}s | prefill {st.prefill_tps:.0f} "

@@ -23,6 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.perturb_ctx import sub as _sub
 from repro.optim.quant import deq as _deq
 from repro.optim.quant import take_rows as _take_rows
@@ -324,10 +325,12 @@ def embed_apply(cfg, p, tokens, positions=None, ctx=None):
 def unembed(cfg, embed_p, head_p, x, ctx=None):
     """Final projection to vocab logits (tied or untied). ctx is scoped to
     the param-tree ROOT here (the two branches touch different leaves)."""
-    if cfg.tie_embeddings or head_p is None:
-        if ctx is None:
-            return x @ _deq(embed_p["tok"]).T
-        # tied head reads the embedding transposed; the row-major z-field
-        # doesn't transpose into kernel tiles, so perturb transiently
-        return x @ ctx.scope("embed").perturb("tok", embed_p["tok"]).T
-    return dense(head_p, x, _sub(ctx, "lm_head"))
+    with jax.named_scope(obs.LM_HEAD):
+        if cfg.tie_embeddings or head_p is None:
+            if ctx is None:
+                return x @ _deq(embed_p["tok"]).T
+            # tied head reads the embedding transposed; the row-major
+            # z-field doesn't transpose into kernel tiles, so perturb
+            # transiently
+            return x @ ctx.scope("embed").perturb("tok", embed_p["tok"]).T
+        return dense(head_p, x, _sub(ctx, "lm_head"))

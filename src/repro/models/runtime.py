@@ -41,6 +41,7 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.perturb_ctx import sub as _sub
 from repro.models import layers as L
 from repro.models.blocks import RunCtx, get_block
@@ -358,10 +359,11 @@ def loss(plan: ModelPlan, params, batch, perturb=None):
     perturbed forward: params stay untouched, every weight use applies
     coeff*z in place (see core/perturb_ctx.py) -- in every family."""
     logits, aux = forward(plan, params, batch, perturb=perturb)
-    if plan.cfg.n_classes:                            # roberta/SST-2 path
-        return softmax_xent(logits, batch["label"])
-    ce = softmax_xent(logits, batch["targets"], batch.get("loss_mask"))
-    return ce + AUX_LOSS_WEIGHT * aux
+    with jax.named_scope(obs.LOSS):
+        if plan.cfg.n_classes:                        # roberta/SST-2 path
+            return softmax_xent(logits, batch["label"])
+        ce = softmax_xent(logits, batch["targets"], batch.get("loss_mask"))
+        return ce + AUX_LOSS_WEIGHT * aux
 
 
 def init_cache(plan: ModelPlan, bsz, max_len, dtype):

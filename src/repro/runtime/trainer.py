@@ -23,6 +23,7 @@ from typing import Any, Callable, Iterator, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import rng as zrng
 from repro.core.engine import (TrainState, build_strategy, get_strategy,
@@ -139,6 +140,33 @@ class Trainer:
             self._pending.clear()
 
     # -- main loop --------------------------------------------------------
+    def run_step(self, state: TrainState, step: int):
+        """The loop body: next batch, straggler mask, the strategy's
+        step (or Adam's), replay-log append and snapshot. Returns
+        ``(state, aux)``; ``aux`` is None for Adam. Dispatches without
+        waiting on the device, except where the replay log reads gs."""
+        with obs.span("train.batch"):
+            batch = {k: jnp.asarray(v) for k, v in next(self.batches).items()}
+            seed = zrng.fold_seed(jnp.uint32(self.tcfg.seed), step)
+        mask = None
+        if self.strategy is None:
+            p, opt, loss = grad_train_step(
+                self.model.loss, state.params, batch, state.opt,
+                self.tcfg.adam)
+            state = TrainState(params=p, step=jnp.uint32(step + 1), opt=opt)
+            aux = None
+            self._pending.append(loss)
+        else:
+            if self._straggler:
+                mask = jnp.asarray(self._straggler.mask())
+            state, aux = self.strategy.step(
+                self.model.loss, state, batch, seed, self._mezo_cfg(), mask,
+                step=step)
+            self._pending.append(aux.loss)
+        if self.ckpt:
+            self.ckpt.on_step(step, state, aux, direction_mask=mask)
+        return state, aux
+
     def train(self, params: Optional[PyTree] = None,
               fail_at: Optional[int] = None) -> PyTree:
         """Runs to n_steps with auto-resume. ``fail_at`` raises at that
@@ -160,28 +188,7 @@ class Trainer:
         for step in range(start, self.tcfg.n_steps):
             if fail_at is not None and step == fail_at:
                 raise RuntimeError(f"injected failure at step {step}")
-            batch = next(self.batches)
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            seed = zrng.fold_seed(jnp.uint32(self.tcfg.seed), step)
-
-            mask = None
-            if self.strategy is None:
-                p, opt, loss = grad_train_step(
-                    self.model.loss, state.params, batch, state.opt,
-                    self.tcfg.adam)
-                state = TrainState(params=p, step=jnp.uint32(step + 1),
-                                   opt=opt)
-                aux = None
-                self._pending.append(loss)
-            else:
-                if self._straggler:
-                    mask = jnp.asarray(self._straggler.mask())
-                state, aux = self.strategy.step(
-                    self.model.loss, state, batch, seed, mcfg, mask)
-                self._pending.append(aux.loss)
-
-            if self.ckpt:
-                self.ckpt.on_step(step, state, aux, direction_mask=mask)
+            state, _ = self.run_step(state, step)
             if step % self.tcfg.log_every == 0:
                 self._sync_losses()
                 dt = time.perf_counter() - t0

@@ -113,6 +113,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.batching import masked_merge
 from repro.models import build_model
 from repro.serve import sampling
@@ -143,6 +144,9 @@ class Completion:
     accept_rate: Optional[float] = None   # draft acceptance (spec mode)
     queue_wait_s: float = 0.0     # submit -> admission start
     ttft_s: float = 0.0           # submit -> first token picked
+    # host perf_counter time at which each token was committed:
+    # ttft_s is token_ts[0] - submit_ts, the gaps between tokens follow
+    token_ts: List[float] = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -453,6 +457,7 @@ class ServeEngine:
         self._remaining = np.zeros(n_slots, np.int32)
         self._last = np.zeros(n_slots, np.int32)
         self._out: List[List[int]] = [[] for _ in range(n_slots)]
+        self._ts: List[List[float]] = [[] for _ in range(n_slots)]
         self._slot_drafted = np.zeros(n_slots, np.int64)
         self._slot_accepted = np.zeros(n_slots, np.int64)
         self._queue_wait = np.zeros(n_slots)
@@ -465,6 +470,11 @@ class ServeEngine:
     # ---- page pool -------------------------------------------------------
     def _pages_needed(self, tokens: int) -> int:
         return -(-tokens // self.page_size)
+
+    def _alloc_prompt_pages(self, slot: int, plen: int) -> None:
+        with obs.span("serve.pages"):
+            for _ in range(self._pages_needed(plen)):
+                self._alloc_page(slot)
 
     def _alloc_page(self, slot: int) -> None:
         page = self._free_pages.pop()
@@ -504,6 +514,11 @@ class ServeEngine:
         self.queue.append(req)
         return req.rid
 
+    def _params(self, user: Optional[str]):
+        """The adapter's weights (replayed on a store miss)."""
+        with obs.span("serve.materialize", user=lambda: str(user)):
+            return self.store.materialize(user)
+
     def _free_slots(self) -> List[int]:
         return [i for i in range(self.n_slots) if not self._active[i]]
 
@@ -530,7 +545,7 @@ class ServeEngine:
                 if self._reserved + need > self.pool_pages - 1:
                     return                       # wait for pages to free
             self.queue.popleft()
-            params = self.store.materialize(req.user)
+            params = self._params(req.user)
             prompt = np.asarray(req.prompt, np.int32).reshape(1, -1)
             t0 = time.perf_counter()
             self._queue_wait[slot] = (
@@ -538,10 +553,8 @@ class ServeEngine:
             if self.paged:
                 self._reserved += need
                 self._slot_reserve[slot] = need
-                n_prompt_pages = self._pages_needed(plen)
-                for _ in range(n_prompt_pages):
-                    self._alloc_page(slot)
-                fresh_len = n_prompt_pages * self.page_size
+                self._alloc_prompt_pages(slot, plen)
+                fresh_len = self._pages_needed(plen) * self.page_size
             else:
                 # bucket the throwaway prefill cache to the next power
                 # of two >= plen instead of a full max_len strip: short
@@ -549,23 +562,25 @@ class ServeEngine:
                 # compiles once per bucket (mirroring _live_pages)
                 fresh_len = min(1 << max(plen - 1, 0).bit_length(),
                                 self.max_len)
-            fresh = self.model.init_cache(1, fresh_len)
-            if self._fns["prefill"] is not None:
-                logits, fresh = self._fns["prefill"](params, fresh,
-                                                     jnp.asarray(prompt))
-            else:
-                toks = jnp.asarray(prompt)
-                for t in range(plen):
-                    logits, fresh = self._fns["decode_one"](
-                        params, fresh, toks[:, t:t + 1], jnp.int32(t))
-            if self.paged:
-                phys = jnp.asarray(
-                    np.asarray(self._slot_alloc[slot], np.int32))
-                self.cache = self._fns["install_paged"](
-                    self.cache, fresh, phys, slot)
-            else:
-                self.cache = self._fns["install"](self.cache, fresh, slot)
-            jax.block_until_ready(self.cache)
+            with obs.span("serve.admit", prompt=plen, chunk=plen):
+                fresh = self.model.init_cache(1, fresh_len)
+                if self._fns["prefill"] is not None:
+                    logits, fresh = self._fns["prefill"](
+                        params, fresh, jnp.asarray(prompt))
+                else:
+                    toks = jnp.asarray(prompt)
+                    for t in range(plen):
+                        logits, fresh = self._fns["decode_one"](
+                            params, fresh, toks[:, t:t + 1], jnp.int32(t))
+                if self.paged:
+                    phys = jnp.asarray(
+                        np.asarray(self._slot_alloc[slot], np.int32))
+                    self.cache = self._fns["install_paged"](
+                        self.cache, fresh, phys, slot)
+                else:
+                    self.cache = self._fns["install"](self.cache, fresh,
+                                                      slot)
+                jax.block_until_ready(self.cache)
             elapsed = time.perf_counter() - t0
             self.stats.prefill_s += elapsed
             self.stats.decode_stall_s += elapsed * int(self._active.sum())
@@ -598,8 +613,7 @@ class ServeEngine:
                         else 0.0)
                     self._reserved += need
                     self._slot_reserve[slot] = need
-                    for _ in range(self._pages_needed(plen)):
-                        self._alloc_page(slot)
+                    self._alloc_prompt_pages(slot, plen)
                     self._req[slot] = req
                     self._prefill_slot = slot
                     self._prefill_off = 0
@@ -610,7 +624,7 @@ class ServeEngine:
         req = self._req[slot]
         prompt = np.asarray(req.prompt, np.int32)
         plen = prompt.size
-        params = self.store.materialize(req.user)
+        params = self._params(req.user)
         n_live = 1
         while n_live < len(self._slot_alloc[slot]):
             n_live *= 2
@@ -620,20 +634,22 @@ class ServeEngine:
         t0 = time.perf_counter()
         done = 0
         logits = None
-        while budget > 0 and self._prefill_off < plen:
-            c = min(plen - self._prefill_off, budget)
-            if c < self.prefill_chunk:   # pow2 tail pieces: bounded shapes
-                c = 1 << (c.bit_length() - 1)
-            end = self._prefill_off + c
-            logits, self.cache = self._fns["prefill_chunk"](
-                params, self.cache,
-                jnp.asarray(prompt[None, self._prefill_off:end]),
-                jnp.asarray([self._prefill_off], np.int32), pages,
-                jnp.int32(slot))
-            self._prefill_off = end
-            budget -= c
-            done += c
-        jax.block_until_ready(self.cache)
+        with obs.span("serve.admit", prompt=plen,
+                      chunk=min(budget, plen - self._prefill_off)):
+            while budget > 0 and self._prefill_off < plen:
+                c = min(plen - self._prefill_off, budget)
+                if c < self.prefill_chunk:   # pow2 tail pieces: bounded
+                    c = 1 << (c.bit_length() - 1)
+                end = self._prefill_off + c
+                logits, self.cache = self._fns["prefill_chunk"](
+                    params, self.cache,
+                    jnp.asarray(prompt[None, self._prefill_off:end]),
+                    jnp.asarray([self._prefill_off], np.int32), pages,
+                    jnp.int32(slot))
+                self._prefill_off = end
+                budget -= c
+                done += c
+            jax.block_until_ready(self.cache)
         elapsed = time.perf_counter() - t0
         self.stats.prefill_s += elapsed
         self.stats.decode_stall_s += elapsed * int(self._active.sum())
@@ -653,6 +669,7 @@ class ServeEngine:
         self.key, sub = jax.random.split(self.key)
         tok = self._pick(req, jax.random.fold_in(sub, slot), logits_row)
         now = time.perf_counter()
+        self._ts[slot] = [now]
         self._ttft[slot] = (now - req.submit_ts
                             if req.submit_ts is not None else 0.0)
         self.stats.queue_wait_s += float(self._queue_wait[slot])
@@ -679,20 +696,22 @@ class ServeEngine:
         return int(np.asarray(tok)[0])
 
     def _finish(self, slot: int):
-        req = self._req[slot]
-        drafted = int(self._slot_drafted[slot])
-        self._finished.append(Completion(
-            rid=req.rid, user=req.user, prompt=np.asarray(req.prompt),
-            tokens=np.asarray(self._out[slot], np.int32),
-            accept_rate=(int(self._slot_accepted[slot]) / drafted
-                         if drafted else None),
-            queue_wait_s=float(self._queue_wait[slot]),
-            ttft_s=float(self._ttft[slot])))
-        self._active[slot] = False
-        self._req[slot] = None
-        if self.paged:
-            self._release_slot_pages(slot)
-        self.stats.finished += 1
+        with obs.span("serve.finish"):
+            req = self._req[slot]
+            drafted = int(self._slot_drafted[slot])
+            self._finished.append(Completion(
+                rid=req.rid, user=req.user, prompt=np.asarray(req.prompt),
+                tokens=np.asarray(self._out[slot], np.int32),
+                accept_rate=(int(self._slot_accepted[slot]) / drafted
+                             if drafted else None),
+                queue_wait_s=float(self._queue_wait[slot]),
+                ttft_s=float(self._ttft[slot]), token_ts=self._ts[slot]))
+            self._ts[slot] = []
+            self._active[slot] = False
+            self._req[slot] = None
+            if self.paged:
+                self._release_slot_pages(slot)
+            self.stats.finished += 1
 
     # ---- decode ---------------------------------------------------------
     def _live_pages(self, cover: np.ndarray):
@@ -702,16 +721,17 @@ class ServeEngine:
         return the (n_slots, n_live) table slice spanning every live
         page -- n_live bucketed to powers of two so the decode dispatch
         compiles once per bucket, not once per length."""
-        for slot in np.flatnonzero(self._active):
-            while (len(self._slot_alloc[slot])
-                   <= cover[slot] // self.page_size):
-                self._alloc_page(slot)          # reservation guarantees one
-        maxp = 1 + int(cover[self._active].max()) // self.page_size
-        n_live = 1
-        while n_live < maxp:
-            n_live *= 2
-        n_live = min(n_live, self.slot_pages)
-        return jnp.asarray(self._table[:, :n_live])
+        with obs.span("serve.pages"):
+            for slot in np.flatnonzero(self._active):
+                while (len(self._slot_alloc[slot])
+                       <= cover[slot] // self.page_size):
+                    self._alloc_page(slot)      # reservation guarantees one
+            maxp = 1 + int(cover[self._active].max()) // self.page_size
+            n_live = 1
+            while n_live < maxp:
+                n_live *= 2
+            n_live = min(n_live, self.slot_pages)
+            return jnp.asarray(self._table[:, :n_live])
 
     def _spec_step(self):
         """One speculative round: base drafts up to ``spec_k`` tokens per
@@ -720,21 +740,23 @@ class ServeEngine:
         correction/bonus token) is committed. Greedy slots accept by
         exact argmax prefix match -- output is bit-identical to the
         plain engine; sampled slots run speculative rejection sampling
-        (:func:`repro.serve.sampling.spec_accept`)."""
+        (:func:`repro.serve.sampling.spec_accept`). Returns the number of
+        distinct adapters the round verified with."""
         self._admit()
         if not self._active.any():
-            return
+            return 0
         t0 = time.perf_counter()
         k = self.spec_k
         act = self._active.copy()
         d = np.where(act, np.minimum(k, self._remaining), 0).astype(np.int32)
         pos_np = np.minimum(self._pos, self.max_len - 1)
         pages = self._live_pages(pos_np + d)
-        drafts, self.cache = self._fns["draft_spec"](
-            self.store.materialize(None), self.cache,
-            jnp.asarray(self._last), jnp.asarray(pos_np), pages,
-            jnp.asarray(d), k)
-        drafts = np.asarray(drafts)                     # (k, n_slots)
+        with obs.span("serve.draft"):
+            drafts, self.cache = self._fns["draft_spec"](
+                self._params(None), self.cache,
+                jnp.asarray(self._last), jnp.asarray(pos_np), pages,
+                jnp.asarray(d), k)
+            drafts = np.asarray(drafts)                 # (k, n_slots)
         win = np.concatenate([self._last.reshape(-1, 1), drafts.T],
                              axis=1).astype(np.int32)   # (n_slots, k+1)
         win_len = d + 1
@@ -742,6 +764,7 @@ class ServeEngine:
         # slot's request mid-round; masks stay disjoint across users
         slot_user = {i: self._req[i].user for i in np.flatnonzero(act)}
         users = set(slot_user.values())
+        keys = None
         if any(not self._req[i].greedy for i in np.flatnonzero(act)):
             self.key, keys = sampling.step_keys(self.key, self.n_slots)
             keys = np.asarray(keys)
@@ -751,62 +774,82 @@ class ServeEngine:
                              for i in range(self.n_slots)])
             wmask = mask[:, None] & (np.arange(k + 1)[None, :]
                                      < win_len[:, None])
-            params = self.store.materialize(u)
-            lg, vstate = self._fns["verify_spec"](
-                params, self.cache, jnp.asarray(win), jnp.asarray(pos_np),
-                pages, jnp.asarray(wmask))
-            lg = np.asarray(lg, np.float32)             # (n_slots, k+1, V)
-            acc = np.zeros(self.n_slots, np.int32)
-            committed: Dict[int, List[int]] = {}
-            for slot in np.flatnonzero(mask):
-                req = self._req[slot]
-                ds = int(d[slot])
-                rem = int(self._remaining[slot])        # >= 1 while active
-                if req.greedy:
-                    tgt = lg[slot, :ds + 1].argmax(axis=1).astype(np.int32)
-                    a = 0
-                    while a < ds and drafts[a, slot] == tgt[a]:
-                        a += 1
-                    toks = tgt[:min(a + 1, rem)].tolist()
-                else:
-                    n_acc, nxt = sampling.spec_accept(
-                        jnp.asarray(keys[slot]),
-                        jnp.asarray(drafts[:ds, slot]),
-                        jnp.asarray(lg[slot, :ds + 1]),
-                        req.topk or self.cfg.vocab, req.temperature)
-                    a = int(n_acc)
-                    toks = (drafts[:a, slot].tolist()
-                            + [int(np.asarray(nxt))])[:min(a + 1, rem)]
-                committed[slot] = toks
-                acc[slot] = len(toks) - 1      # state after consuming
-                #                                window offsets [0, len)
-                self._slot_drafted[slot] += ds
-                self._slot_accepted[slot] += min(a, len(toks))
-                self.stats.spec_drafted += ds
-                self.stats.spec_accepted += min(a, len(toks))
-            self.cache = self._fns["commit_spec"](
-                self.cache, vstate, jnp.asarray(acc), jnp.asarray(mask))
-            for slot, toks in committed.items():
-                self._out[slot].extend(toks)
-                self._last[slot] = toks[-1]
-                self._pos[slot] += len(toks)
-                self._remaining[slot] -= len(toks)
-                n_committed += len(toks)
-                if (self._remaining[slot] == 0
-                        or self._pos[slot] >= self.max_len - 1):
-                    self._finish(slot)
+            params = self._params(u)
+            with obs.span("serve.verify", slots=lambda: int(mask.sum())):
+                lg, vstate = self._fns["verify_spec"](
+                    params, self.cache, jnp.asarray(win),
+                    jnp.asarray(pos_np), pages, jnp.asarray(wmask))
+            with obs.span("serve.accept"):
+                committed = self._accept(mask, d, drafts, lg, keys)
+            with obs.span("serve.commit"):
+                acc = np.zeros(self.n_slots, np.int32)
+                for slot, toks in committed.items():
+                    acc[slot] = len(toks) - 1  # state after consuming
+                    #                            window offsets [0, len)
+                self.cache = self._fns["commit_spec"](
+                    self.cache, vstate, jnp.asarray(acc), jnp.asarray(mask))
+                now = time.perf_counter()
+                for slot, toks in committed.items():
+                    self._out[slot].extend(toks)
+                    self._ts[slot].extend([now] * len(toks))
+                    self._last[slot] = toks[-1]
+                    self._pos[slot] += len(toks)
+                    self._remaining[slot] -= len(toks)
+                    n_committed += len(toks)
+                    if (self._remaining[slot] == 0
+                            or self._pos[slot] >= self.max_len - 1):
+                        self._finish(slot)
         self.stats.decode_s += time.perf_counter() - t0
         self.stats.decode_tokens += n_committed
         self.stats.decode_steps += 1
+        return len(users)
+
+    def _accept(self, mask, d, drafts, lg, keys) -> Dict[int, List[int]]:
+        """The verify logits to the host, then per masked slot the
+        tokens to commit: the accepted draft prefix plus the target's
+        correction or bonus token."""
+        lg = np.asarray(lg, np.float32)                 # (n_slots, k+1, V)
+        committed: Dict[int, List[int]] = {}
+        for slot in np.flatnonzero(mask):
+            req = self._req[slot]
+            ds = int(d[slot])
+            rem = int(self._remaining[slot])        # >= 1 while active
+            if req.greedy:
+                tgt = lg[slot, :ds + 1].argmax(axis=1).astype(np.int32)
+                a = 0
+                while a < ds and drafts[a, slot] == tgt[a]:
+                    a += 1
+                toks = tgt[:min(a + 1, rem)].tolist()
+            else:
+                n_acc, nxt = sampling.spec_accept(
+                    jnp.asarray(keys[slot]),
+                    jnp.asarray(drafts[:ds, slot]),
+                    jnp.asarray(lg[slot, :ds + 1]),
+                    req.topk or self.cfg.vocab, req.temperature)
+                a = int(n_acc)
+                toks = (drafts[:a, slot].tolist()
+                        + [int(np.asarray(nxt))])[:min(a + 1, rem)]
+            committed[slot] = toks
+            self._slot_drafted[slot] += ds
+            self._slot_accepted[slot] += min(a, len(toks))
+            self.stats.spec_drafted += ds
+            self.stats.spec_accepted += min(a, len(toks))
+        return committed
 
     def step(self):
         """Admit whatever fits, then advance every active slot one token
-        (or one speculative window when ``spec_k`` is set)."""
-        if self.spec_k:
-            return self._spec_step()
+        (or one speculative window when ``spec_k`` is set). Its span
+        carries the distinct adapters the round ran."""
+        with obs.span("serve.step", active=lambda: int(self._active.sum()),
+                      queued=lambda: len(self.queue)) as sp:
+            n = self._spec_step() if self.spec_k else self._decode_step()
+            sp.set_metadata(adapters=n)
+
+    def _decode_step(self) -> int:
+        """One plain decode step; returns the distinct adapters run."""
         self._admit()
         if not self._active.any():
-            return
+            return 0
         t0 = time.perf_counter()
         toks = jnp.asarray(self._last.reshape(self.n_slots, 1))
         pos_np = np.minimum(self._pos, self.max_len - 1)
@@ -820,7 +863,7 @@ class ServeEngine:
         # pages (not trash) and its dense recurrent lane is mid-advance,
         # so the all-slots fast path would corrupt both
         if len(users) == 1 and self._prefill_slot is None:
-            params = self.store.materialize(next(iter(users)))
+            params = self._params(next(iter(users)))
             if self.paged:
                 lg, self.cache = self._fns["decode_all_paged"](
                     params, self.cache, toks, pos, pages)
@@ -833,7 +876,7 @@ class ServeEngine:
                 mask = np.array([self._active[i]
                                  and self._req[i].user == u
                                  for i in range(self.n_slots)])
-                params = self.store.materialize(u)
+                params = self._params(u)
                 if self.paged:
                     lg, self.cache = self._fns["decode_masked_paged"](
                         params, self.cache, toks, pos, pages,
@@ -860,8 +903,10 @@ class ServeEngine:
             toks_s = sampling.sample_topk(keys[np.asarray(slots)],
                                           jnp.asarray(merged[slots]), k, temp)
             picked.update(zip(slots, np.asarray(toks_s).tolist()))
+        now = time.perf_counter()
         for slot, tok in picked.items():
             self._out[slot].append(tok)
+            self._ts[slot].append(now)
             self._last[slot] = tok
             self._pos[slot] += 1
             self._remaining[slot] -= 1
@@ -871,6 +916,7 @@ class ServeEngine:
         self.stats.decode_s += time.perf_counter() - t0
         self.stats.decode_tokens += n_active
         self.stats.decode_steps += 1
+        return len(users)
 
     def drain_finished(self) -> List[Completion]:
         out, self._finished = self._finished, []

@@ -60,3 +60,19 @@ def test_compile_cache_dir(monkeypatch, env_dir):
     else:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
         assert compile_cache.cache_dir() == env_dir
+
+
+def test_timed_reads_the_program_compile_counter(capsys):
+    """A phase's log line counts the compiles it made, from
+    ``repro.obs.compiles()``."""
+    import jax
+    import jax.numpy as jnp
+    smoke = _load_smoke()
+    x = jnp.ones(3)
+
+    def phase():
+        return jax.jit(lambda v: v * 5 - 2)(x).block_until_ready()
+
+    smoke.timed("phase", phase)
+    assert "of which 1 backend compiles or cache loads" in \
+        capsys.readouterr().out

@@ -362,3 +362,64 @@ def test_engine_latency_observability():
     # three slots decoded while the 23-token prompt prefilled whole: the
     # admission stall must be visible (chunked admission shrinks it)
     assert eng.stats.decode_stall_s > 0.0
+
+
+def test_completion_token_timestamps():
+    """token_ts: one host time per committed token, never decreasing,
+    from at or after submit; ttft_s is its first entry less submit_ts --
+    in plain and in speculative rounds."""
+    cfg = get_config("gemma-2b").reduced()
+    store = AdapterStore(build_model(cfg).init(jax.random.PRNGKey(0)))
+    store.put("alice", _synthetic_records(4, seed=1))
+    rng = np.random.default_rng(3)
+    for spec_k in (None, 2):
+        eng = ServeEngine(cfg, store, n_slots=2, max_len=24, seed=0,
+                          paged=True, page_size=4, spec_k=spec_k)
+        reqs = [Request(prompt=rng.integers(0, cfg.vocab, p).astype(
+            np.int32), max_new=g, user=u)
+            for p, g, u in ((5, 6, "alice"), (7, 4, None), (3, 5, "alice"))]
+        for r in reqs:
+            eng.submit(r)
+        comps = {c.rid: c for c in eng.run()}
+        for r in reqs:
+            c = comps[r.rid]
+            assert len(c.token_ts) == c.tokens.size == r.max_new
+            assert c.token_ts == sorted(c.token_ts)
+            assert c.token_ts[0] >= r.submit_ts
+            assert c.ttft_s == c.token_ts[0] - r.submit_ts
+
+
+def test_traced_round_verifies_once_per_adapter(tmp_path):
+    """A speculative round's repro.serve.step span carries the distinct
+    adapters it ran, and holds that many repro.serve.verify spans."""
+    import glob
+
+    from jax.profiler import ProfileData
+    cfg = get_config("gemma-2b").reduced()
+    store = AdapterStore(build_model(cfg).init(jax.random.PRNGKey(0)))
+    store.put("alice", _synthetic_records(4, seed=1))
+    store.put("bob", _synthetic_records(4, seed=2))
+    eng = ServeEngine(cfg, store, n_slots=2, max_len=24, seed=0,
+                      paged=True, page_size=4, spec_k=2)
+    rng = np.random.default_rng(4)
+    for u in ("alice", "bob"):
+        eng.submit(Request(prompt=rng.integers(0, cfg.vocab, 6).astype(
+            np.int32), max_new=5, user=u))
+    eng.step()                       # admission and the first round compile
+    with jax.profiler.trace(str(tmp_path)):
+        eng.step()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+              dict(ev.stats or ()))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("repro.serve.")]
+    (_, s0, s1, args), = [s for s in spans if s[0] == "repro.serve.step"]
+    verifies = [s for s in spans if s[0] == "repro.serve.verify"
+                and s0 <= s[1] and s[2] <= s1]
+    assert int(args["adapters"]) == len(verifies) == 2
+    assert sorted(int(v[3]["slots"]) for v in verifies) == [1, 1]
+    names = {s[0] for s in spans}
+    assert {"repro.serve.draft", "repro.serve.accept", "repro.serve.commit",
+            "repro.serve.pages", "repro.serve.materialize"} <= names
