@@ -6,7 +6,9 @@ TPU they compile to Mosaic.
 
 Both wrappers accept ``scale=`` (per-output-channel f32 vector) to mark
 ``w`` as an int8 quantized base: dequantization then fuses into the same
-kernel tile pass (see kernels/zo_perturb.py).
+kernel tile pass (see kernels/zo_perturb.py). The matmul wrappers'
+``blocks=None`` picks the tiling from the call's shapes, dtypes and dot
+precision (``zo_perturb.matmul_blocks``); a (bm, bk, bn) tuple pins it.
 """
 
 from __future__ import annotations
@@ -63,7 +65,7 @@ def zo_add(w, seed, salt: int, coeff, dist: str = "rademacher",
 
 
 def zo_matmul(x, w, seed, salt: int, coeff, dist: str = "rademacher",
-              blocks=(128, 128, 128), prime_offset: int = 0,
+              blocks=None, prime_offset: int = 0,
               prehashed: bool = False, scale=None):
     return _k.zo_matmul(x, w, seed, salt, coeff, dist=dist, blocks=blocks,
                         interpret=_INTERPRET, prime_offset=prime_offset,
@@ -80,7 +82,7 @@ def zo_add_users(w, seeds, salt: int, coeffs, dist: str = "rademacher",
 
 
 def zo_matmul_users(x, w, seeds, salt: int, coeffs,
-                    dist: str = "rademacher", blocks=(128, 128, 128),
+                    dist: str = "rademacher", blocks=None,
                     prime_offset: int = 0, prehashed: bool = False,
                     scale=None):
     """B users' perturbed forwards against ONE resident (K, N) base:

@@ -22,8 +22,12 @@ The RNG is the same counter-based avalanche hash as repro.core.rng, keyed
 by absolute (row, col) coordinates, so full-array references in ref.py
 reproduce kernel tiles bit-exactly for any BlockSpec tiling.
 
-Block shapes: (128, 128)-aligned tiles for the MXU; zo_add is a pure
-VPU/memory kernel and uses (256, 256) tiles to amortize grid overhead.
+Block shapes: zo_matmul takes the largest (8, 128)-aligned tiles whose
+VMEM footprint fits the chip's default scoped VMEM (:func:`matmul_blocks`,
+decided from the call's shapes, dtypes and dot precision): each grid
+step costs a fixed overhead, and z is regenerated once per M block, so
+small tiles pay both many times over. zo_add is a pure VPU/memory kernel
+and uses (256, 256) tiles.
 """
 
 from __future__ import annotations
@@ -85,14 +89,16 @@ def _tile_z(seed, salt, shape, row0, col0, dist: str,
 # W + coeff * z
 
 
+def _sizes(dim: int, align: int) -> list[int]:
+    """Every block size the TPU's tiling accepts along ``dim``: the
+    multiples of ``align`` (8 for a sublane axis, 128 for a lane axis)
+    that divide it, else the whole dim."""
+    return [b for b in range(align, dim + 1, align) if dim % b == 0] or [dim]
+
+
 def _pick(dim: int, want: int, align: int) -> int:
-    """Largest block size <= want that divides dim and is a multiple of
-    ``align`` (8 for a sublane axis, 128 for a lane axis), else the whole
-    dim: the two block shapes the TPU's tiling accepts."""
-    for b in range(min(want, dim) // align * align, 0, -align):
-        if dim % b == 0:
-            return b
-    return dim
+    """Largest of :func:`_sizes` that is <= want, else the whole dim."""
+    return max((b for b in _sizes(dim, align) if b <= want), default=dim)
 
 
 def _zo_add_kernel(seed_ref, coeff_ref, w_ref, o_ref, *, salt, bm, bn, dist,
@@ -113,6 +119,62 @@ def _zo_add_q_kernel(seed_ref, coeff_ref, w_ref, s_ref, o_ref, *, salt, bm,
                 prime_offset, prehashed)
     w = w_ref[...].astype(jnp.float32) * s_ref[...]
     o_ref[...] = (w + coeff_ref[0] * z).astype(o_ref.dtype)
+
+
+# Scoped VMEM a Mosaic kernel may hold without asking for more
+# (``vmem_limit_bytes``): the compiler's default on a TPU v5e.
+VMEM_BUDGET = 16 << 20
+
+
+def _dot_at_highest() -> bool:
+    """Whether the perturbed matmul's f32 dot runs at HIGHEST, as JAX's
+    default matmul precision asks where it is set (one bf16 MXU pass
+    otherwise)."""
+    return jax.config.jax_default_matmul_precision in ("highest", "float32")
+
+
+def matmul_vmem_bytes(bm: int, bk: int, bn: int, x_dtype, w_dtype,
+                      scaled: bool = False, highest: bool = False) -> int:
+    """VMEM the perturbed matmul holds for one (bm, bk, bn) grid step:
+    double-buffered x, w and output tiles (the output has x's dtype), the
+    (1, bn) scale row padded to 8 sublanes when ``scaled``, and in f32
+    the accumulator, x's tile cast for the dot and the perturbed weight
+    tile (z is folded into it as it is made). A dot at HIGHEST splits x's
+    tile into bf16 parts: four more f32 x tiles, by what the compiler
+    reserves for a v5e."""
+    xb, wb = jnp.dtype(x_dtype).itemsize, jnp.dtype(w_dtype).itemsize
+    pipelined = bm * bk * xb + bk * bn * wb + bm * bn * xb \
+        + (8 * bn * 4 if scaled else 0)
+    x_tiles = 5 if highest else 1
+    return 2 * pipelined + 4 * (bm * bn + x_tiles * bm * bk + bk * bn)
+
+
+def matmul_blocks(m: int, k: int, n: int, x_dtype, w_dtype,
+                  scaled: bool = False,
+                  highest: bool = False) -> tuple[int, int, int]:
+    """(bm, bk, bn) for X (m, k) @ W (k, n): of the tilings
+    :func:`_sizes` allows whose :func:`matmul_vmem_bytes` fits
+    :data:`VMEM_BUDGET`, the one with the fewest grid steps; ties go to
+    the larger bm (z is regenerated m / bm times), then the larger bn (x
+    is read n / bn times). Where none fits, the smallest tiling."""
+    cands = [(bm, bk, bn) for bm in _sizes(m, 8) for bk in _sizes(k, 128)
+             for bn in _sizes(n, 128)]
+    fits = [b for b in cands
+            if matmul_vmem_bytes(*b, x_dtype, w_dtype, scaled, highest)
+            <= VMEM_BUDGET]
+    if not fits:
+        return cands[0]
+    return max(fits, key=lambda b: (b[0] * b[1] * b[2], b[0], b[2]))
+
+
+def _matmul_tiling(blocks, m, k, n, x_dtype, w_dtype, scaled):
+    """``blocks=None`` picks the tiling; a given (bm, bk, bn) is snapped
+    to the nearest accepted sizes at or below it."""
+    if blocks is None:
+        return matmul_blocks(m, k, n, x_dtype, w_dtype, scaled,
+                             _dot_at_highest())
+    return (_pick(m, blocks[0], 8), _pick(k, blocks[1], 128),
+            _pick(n, blocks[2], 128))
 
 
 @functools.partial(jax.jit,
@@ -268,7 +330,7 @@ def _zo_matmul_q_kernel(seed_ref, coeff_ref, x_ref, w_ref, s_ref, o_ref,
                    static_argnames=("salt", "dist", "blocks", "interpret",
                                     "prime_offset", "prehashed"))
 def zo_matmul(x, w, seed, salt: int, coeff, dist: str = "rademacher",
-              blocks=(128, 128, 128), interpret: bool = False,
+              blocks=None, interpret: bool = False,
               prime_offset: int = 0, prehashed: bool = False, scale=None):
     """Y = X @ (W + coeff * z(seed)). X: (M, K), W: (K, N).
 
@@ -284,12 +346,15 @@ def zo_matmul(x, w, seed, salt: int, coeff, dist: str = "rademacher",
     prehashed/prime_offset: see :func:`_tile_z` -- lets the kernel compute
     the perturbed forward for one layer-slice of a scan-stacked (L, K, N)
     leaf while staying bit-exact with the full-leaf reference field.
+
+    blocks: None picks (bm, bk, bn) from the shapes, the dtypes and the
+    dot's precision (:func:`matmul_blocks`); a tuple pins the tiling.
     """
     m, k = x.shape
     k2, n = w.shape
     assert k == k2
-    bm, bk, bn = (_pick(m, blocks[0], 8), _pick(k, blocks[1], 128),
-                  _pick(n, blocks[2], 128))
+    bm, bk, bn = _matmul_tiling(blocks, m, k, n, x.dtype, w.dtype,
+                                scale is not None)
     grid = (m // bm, n // bn, k // bk)
     seed = jnp.asarray(seed, _U32).reshape(1)
     coeff = jnp.asarray(coeff, jnp.float32).reshape(1)
@@ -385,7 +450,7 @@ def _zo_matmul_users_q_kernel(seed_ref, coeff_ref, x_ref, w_ref, s_ref,
                    static_argnames=("salt", "dist", "blocks", "interpret",
                                     "prime_offset", "prehashed"))
 def zo_matmul_users(x, w, seeds, salt: int, coeffs,
-                    dist: str = "rademacher", blocks=(128, 128, 128),
+                    dist: str = "rademacher", blocks=None,
                     interpret: bool = False, prime_offset: int = 0,
                     prehashed: bool = False, scale=None):
     """User-batched :func:`zo_matmul`: ``Y[u] = X[u] @ (W +
@@ -395,9 +460,10 @@ def zo_matmul_users(x, w, seeds, salt: int, coeffs,
     This is the multi-tenant hot path: one dispatch evaluates B users'
     perturbed forwards against one copy of the weights. The user axis is
     the grid's outermost dimension with the k-reduction innermost, and
-    block sizes match the scalar kernel's, so each lane's accumulation
-    order -- and therefore its bits -- is identical to a lone
-    :func:`zo_matmul` call with that user's (seed, coeff).
+    block sizes are picked from one lane's shapes exactly as the scalar
+    kernel picks them, so each lane's accumulation order -- and therefore
+    its bits -- is identical to a lone :func:`zo_matmul` call with that
+    user's (seed, coeff).
 
     scale: per-output-channel (N,) f32 scales marking ``w`` as an int8
     quantized base; dequant fuses into the same VMEM tile pass, so U
@@ -406,8 +472,8 @@ def zo_matmul_users(x, w, seeds, salt: int, coeffs,
     u, m, k = x.shape
     k2, n = w.shape
     assert k == k2
-    bm, bk, bn = (_pick(m, blocks[0], 8), _pick(k, blocks[1], 128),
-                  _pick(n, blocks[2], 128))
+    bm, bk, bn = _matmul_tiling(blocks, m, k, n, x.dtype, w.dtype,
+                                scale is not None)
     grid = (u, m // bm, n // bn, k // bk)
     seeds = jnp.asarray(seeds, _U32).reshape(u)
     coeffs = jnp.asarray(coeffs, jnp.float32).reshape(u)

@@ -1,12 +1,14 @@
 """Every main-path Pallas kernel compiles for a described TPU v5e at
-opt-1.3b widths (d_model 2048, d_ff 8192, 32 heads of 64, pages of 16).
+opt-1.3b widths (d_model 2048, d_ff 8192, 32 heads of 64, pages of 16),
+and the fused ZO matmul also at roberta-large's f32 widths.
 
 Nothing runs: the TPU compiler, installed here, compiles for a chip that
 is described and not attached, and refuses what the chip would refuse
-(block shapes off the (8, 128) tiling, casts Mosaic lacks) -- which
-interpret mode never checks. The topology is described inside a fixture,
-never at import: only one process may load the TPU library at a time,
-and pytest-xdist workers import every test file.
+(block shapes off the (8, 128) tiling, casts Mosaic lacks, tiles that
+overflow the scoped VMEM) -- which interpret mode never checks. The
+topology is described inside a fixture, never at import: only one
+process may load the TPU library at a time, and pytest-xdist workers
+import every test file.
 """
 
 import os
@@ -21,6 +23,7 @@ from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.flash_verify import flash_verify
 
 D, F = 2048, 8192                    # opt-1.3b d_model, d_ff
+RD, RF = 1024, 4096                  # roberta-large d_model, d_ff
 H, KV, HD, PS = 32, 32, 64, 16       # heads, kv heads, head_dim, page size
 SLOTS, N_LIVE = 4, 18                # 4 slots of 288 tokens
 N_PAGES = SLOTS * N_LIVE + 1
@@ -49,6 +52,12 @@ def _zo_matmul(x, w, s, c):
     return zp.zo_matmul(x, w, s, 0, c)
 
 
+def _zo_matmul_highest(x, w, s, c):
+    """As roberta-large runs it: f32 products at HIGHEST."""
+    with jax.default_matmul_precision("highest"):
+        return zp.zo_matmul(x, w, s, 0, c)
+
+
 def _zo_matmul_q(x, w, sc, s, c):
     return zp.zo_matmul(x, w, s, 0, c, scale=sc)
 
@@ -73,6 +82,28 @@ CASES = {
                                     ((), U32), ((), F32)]),
     "zo_matmul_bf16_m200": (_zo_matmul, [((200, D), BF), ((D, F), BF),
                                          ((), U32), ((), F32)]),
+    # the train cells' projections at their picked tiles: opt-1.3b in
+    # bf16 (8 x 512 rows), roberta-large in f32 (64 x 128 rows), at the
+    # default precision and at HIGHEST
+    "zo_matmul_bf16_attn": (_zo_matmul, [((4096, D), BF), ((D, D), BF),
+                                         ((), U32), ((), F32)]),
+    "zo_matmul_bf16_w_out": (_zo_matmul, [((4096, F), BF), ((F, D), BF),
+                                          ((), U32), ((), F32)]),
+    "zo_matmul_f32_rl_attn": (_zo_matmul, [((8192, RD), F32),
+                                           ((RD, RD), F32), ((), U32),
+                                           ((), F32)]),
+    "zo_matmul_f32_rl_w_in": (_zo_matmul, [((8192, RD), F32),
+                                           ((RD, RF), F32), ((), U32),
+                                           ((), F32)]),
+    "zo_matmul_f32_rl_w_out": (_zo_matmul, [((8192, RF), F32),
+                                            ((RF, RD), F32), ((), U32),
+                                            ((), F32)]),
+    "zo_matmul_f32_highest_rl_attn": (_zo_matmul_highest, [
+        ((8192, RD), F32), ((RD, RD), F32), ((), U32), ((), F32)]),
+    "zo_matmul_f32_highest_rl_w_in": (_zo_matmul_highest, [
+        ((8192, RD), F32), ((RD, RF), F32), ((), U32), ((), F32)]),
+    "zo_matmul_f32_highest_rl_w_out": (_zo_matmul_highest, [
+        ((8192, RF), F32), ((RF, RD), F32), ((), U32), ((), F32)]),
     "zo_matmul_int8": (_zo_matmul_q, [((512, D), BF), ((D, F), I8),
                                       ((F,), F32), ((), U32), ((), F32)]),
     "zo_matmul_users_int8": (_zo_matmul_users_q, [

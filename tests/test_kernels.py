@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels import zo_perturb as zp
 
 KEY = jax.random.PRNGKey(0)
 
@@ -50,14 +51,100 @@ def test_zo_add_block_invariance():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_zo_matmul_block_invariance():
-    x = jax.random.normal(KEY, (128, 256), jnp.float32) * 0.1
-    w = jax.random.normal(jax.random.fold_in(KEY, 2), (256, 128),
+@pytest.mark.parametrize("mkn,blocks_a,blocks_b", [
+    ((128, 256, 128), (128, 256, 128), (64, 64, 64)),
+    # the picked tiling (whole dims here) against 128-cubed tiles
+    ((256, 512, 384), None, (128, 128, 128)),
+])
+def test_zo_matmul_block_invariance(mkn, blocks_a, blocks_b):
+    m, k, n = mkn
+    if blocks_a is None:
+        assert zp.matmul_blocks(m, k, n, jnp.float32, jnp.float32) \
+            != (128, 128, 128)
+    x = jax.random.normal(KEY, (m, k), jnp.float32) * 0.1
+    w = jax.random.normal(jax.random.fold_in(KEY, 2), (k, n),
                           jnp.float32) * 0.1
-    a = ops.zo_matmul(x, w, 5, 6, 0.5, blocks=(128, 256, 128))
-    b = ops.zo_matmul(x, w, 5, 6, 0.5, blocks=(64, 64, 64))
+    a = ops.zo_matmul(x, w, 5, 6, 0.5, blocks=blocks_a)
+    b = ops.zo_matmul(x, w, 5, 6, 0.5, blocks=blocks_b)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,
                                atol=2e-5)
+
+
+# ---- block picker ---------------------------------------------------------
+
+BF, F32, I8 = jnp.bfloat16, jnp.float32, jnp.int8
+# (m, k, n, x dtype, w dtype, scaled): the train cells' projections --
+# opt-1.3b at 8 x 512 rows (bf16, and its int8 base), roberta-large at
+# 64 x 128 rows (f32) -- and ragged shapes for the whole-dim fallback
+PICK_CASES = [(4096, k, n, xd, wd, sc)
+              for k, n in [(2048, 2048), (2048, 8192), (8192, 2048)]
+              for xd, wd, sc in [(BF, BF, False), (F32, F32, False),
+                                 (BF, I8, True)]] + \
+             [(8192, k, n, F32, F32, False)
+              for k, n in [(1024, 1024), (1024, 4096), (4096, 1024)]] + \
+             [(200, 2048, 8192, BF, BF, False), (32, 100, 60, F32, F32, False),
+              (100, 384, 300, BF, BF, False)]
+
+
+@pytest.mark.parametrize("highest", [False, True])
+@pytest.mark.parametrize("m,k,n,xd,wd,scaled", PICK_CASES)
+def test_matmul_blocks_tile_and_fit(m, k, n, xd, wd, scaled, highest):
+    bm, bk, bn = zp.matmul_blocks(m, k, n, xd, wd, scaled, highest)
+    for dim, b, align in ((m, bm, 8), (k, bk, 128), (n, bn, 128)):
+        assert dim % b == 0
+        assert b % align == 0 or b == dim
+    assert zp.matmul_vmem_bytes(bm, bk, bn, xd, wd, scaled, highest) \
+        <= zp.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("m,k,n,xd,highest,want", [
+    (4096, 2048, 2048, BF, False, (1024, 512, 1024)),
+    (4096, 2048, 8192, BF, False, (1024, 512, 1024)),
+    (4096, 8192, 2048, BF, False, (1024, 512, 1024)),
+    (8192, 1024, 4096, F32, False, (1024, 512, 512)),
+    (8192, 1024, 4096, F32, True, (1024, 256, 512)),
+])
+def test_matmul_blocks_at_cell_shapes(m, k, n, xd, highest, want):
+    """The tiles the train cells' projections run with: f32 operands
+    need twice the VMEM of bf16 ones, and a dot at HIGHEST (roberta-large
+    runs its f32 products so) more again, so they get smaller tiles."""
+    assert zp.matmul_blocks(m, k, n, xd, xd, highest=highest) == want
+
+
+def _grid(fn, *args):
+    """The grid of the one pallas_call inside ``fn(*args)``."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn.params["grid_mapping"].grid
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                got = walk(sub)
+                if got is not None:
+                    return got
+        return None
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("xd,wd,scaled,precision", [
+    (BF, BF, False, None), (F32, F32, False, None), (BF, I8, True, None),
+    (F32, F32, False, "highest")])
+def test_zo_matmul_users_picks_scalar_blocks(xd, wd, scaled, precision):
+    """Each lane of the user-batched kernel tiles as a lone call does (its
+    bit-equality rests on that), at opt-1.3b's FFN shape; both pick for
+    the precision JAX's default asks of their dot."""
+    u, m, k, n = 4, 4096, 2048, 8192
+    sd = jax.ShapeDtypeStruct
+    sc = sd((n,), F32) if scaled else None
+    with jax.default_matmul_precision(precision):
+        one = _grid(lambda x, w, s: zp.zo_matmul(x, w, 1, 0, 0.5, scale=s),
+                    sd((m, k), xd), sd((k, n), wd), sc)
+        many = _grid(lambda x, w, s: zp.zo_matmul_users(
+            x, w, jnp.arange(u, dtype=jnp.uint32), 0, jnp.ones(u),
+            scale=s), sd((u, m, k), xd), sd((k, n), wd), sc)
+    bm, bk, bn = zp.matmul_blocks(m, k, n, xd, wd, scaled,
+                                  highest=precision == "highest")
+    assert one == (m // bm, n // bn, k // bk)
+    assert many == (u,) + one
 
 
 def test_zero_coeff_is_identity_matmul():
