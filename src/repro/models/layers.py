@@ -221,14 +221,14 @@ def attn_apply(cfg, p, x, *, positions=None, kv_mask=None, causal=None,
                           cfg.rope_theta)
         q, k = apply_rope(q, cs), apply_rope(k, cs)
     causal = cfg.causal if causal is None else causal
-    if cfg.attn_impl == "flash" and kv_mask is None:
-        from repro.kernels.flash_attention import flash_attention
-        import jax as _jax
-        out = flash_attention(q, k, v, causal=causal,
-                              interpret=_jax.default_backend() != "tpu")
-    else:
-        out = attention(q, k, v, causal=causal, kv_mask=kv_mask,
-                        chunk=cfg.attn_chunk)
+    with jax.named_scope(obs.ATTENTION):
+        if cfg.attn_impl == "flash" and kv_mask is None:
+            from repro.kernels.flash_attention import flash_attention
+            out = flash_attention(q, k, v, causal=causal,
+                                  interpret=jax.default_backend() != "tpu")
+        else:
+            out = attention(q, k, v, causal=causal, kv_mask=kv_mask,
+                            chunk=cfg.attn_chunk)
     return dense(p["wo"], out.reshape(b, s, -1), _sub(ctx, "wo"))
 
 

@@ -317,9 +317,10 @@ def forward(plan: ModelPlan, params, batch, last_only=False, perturb=None):
     x, aux = _stack_apply(cfg, plan.stack, params, x, rc, perturb)
     x = L.norm_apply(cfg, params["ln_f"], x, _sub(perturb, "ln_f"))
     if cfg.n_classes:                  # CLS pooling + head (roberta/SST-2);
-        cls = x[:, 0].astype(jnp.float32)          # last_only has no meaning
-        return L.dense(params["cls_head"], jnp.tanh(cls),
-                       _sub(perturb, "cls_head")), aux
+        with jax.named_scope(obs.CLS_HEAD):        # last_only has no meaning
+            cls = x[:, 0].astype(jnp.float32)
+            return L.dense(params["cls_head"], jnp.tanh(cls),
+                           _sub(perturb, "cls_head")), aux
     if n_prefix:
         x = x[:, n_prefix:]
     if last_only:          # prefill: only the next-token logits are needed

@@ -23,6 +23,8 @@ FORWARD = "zo.forward"           # one perturbed loss evaluation
 UPDATE = "zo.update"             # the update rule's sweep over params
 LM_HEAD = "runtime.lm_head"      # projection to vocabulary logits
 LOSS = "runtime.loss"            # cross entropy over the logits
+ATTENTION = "runtime.attention"  # attention core: scores, mask, softmax, sum
+CLS_HEAD = "runtime.cls_head"    # classifier: pooling, tanh, 2-class head
 MATMUL = "zo_matmul."            # + a projection's parameter path
 
 # host spans

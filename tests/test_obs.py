@@ -3,6 +3,7 @@ trace recording, appear with their args on the host plane when one
 records, compiles are counted by function name, and the fused step's
 device ops carry the program's scopes."""
 
+import contextlib
 import glob
 import json
 import os
@@ -10,6 +11,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
@@ -102,3 +104,61 @@ def test_fused_step_carries_the_program_scopes(fused_step_hlo):
                            "blocks/mlp/w_in", "blocks/mlp/w_out", "lm_head"}
     for proj in projections:
         assert any(f"/{obs.MATMUL}{proj}/" in n for n in names), proj
+
+
+# ---------------------------------------------------------------------------
+# the attention core and the classification head, in both train models
+
+SMALL_STEP = {"opt-1.3b": {"targets": jnp.zeros((2, 16), jnp.int32)},
+              "roberta-large": {"label": jnp.array([0, 1], jnp.int32)}}
+
+
+def _small_step(arch, scoped=True):
+    """(compiled HLO text, loss, gs) of one fused step (zo_matmul kernel
+    on) of the benchmark's ``arch`` at reduced shapes; ``scoped=False``
+    takes the attention and head scopes out (each call traces the step
+    afresh: its loss function is new)."""
+    with open(os.path.join(ROOT, "bench", "configs", f"{arch}.json")) as f:
+        cfg = ModelConfig(**json.load(f)["model"]).reduced()
+    model = build_model(cfg)
+    strategy = build_strategy("fused", "sgd")
+    mezo = MezoConfig(lr=1e-6, eps=1e-3, use_kernel=True)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
+                                          cfg.vocab), **SMALL_STEP[arch]}
+    real = jax.named_scope
+    taken_out = (obs.ATTENTION, obs.CLS_HEAD)
+    jax.named_scope = (real if scoped else lambda name: (
+        contextlib.nullcontext() if name in taken_out else real(name)))
+    loss_fn = lambda *a, **k: model.loss(*a, **k)     # noqa: E731
+    try:
+        state = strategy.init_state(model.init(jax.random.PRNGKey(0)), mezo)
+        text = strategy.lower(loss_fn, state, batch, jnp.uint32(1),
+                              mezo).compile().as_text()
+        _, aux = strategy.step(loss_fn, state, batch, jnp.uint32(1), mezo)
+        return text, np.asarray(aux.loss), np.asarray(aux.gs)
+    finally:
+        jax.named_scope = real
+
+
+@pytest.fixture(scope="module", params=sorted(SMALL_STEP))
+def small_steps(request):
+    return (request.param, _small_step(request.param),
+            _small_step(request.param, scoped=False))
+
+
+def test_attention_core_and_head_carry_their_scopes(small_steps):
+    arch, (text, _, _), (bare, _, _) = small_steps
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any(f"/{obs.ATTENTION}/" in n for n in names)
+    # the projections keep their own scopes, outside the attention core
+    assert not any(obs.ATTENTION in n and obs.MATMUL in n for n in names)
+    head = any(f"/{obs.CLS_HEAD}/" in n for n in names)
+    assert head == (arch == "roberta-large")
+    assert obs.ATTENTION not in bare and obs.CLS_HEAD not in bare
+
+
+def test_attention_and_head_scopes_leave_the_step_bit_identical(
+        small_steps):
+    _, (_, loss, gs), (_, loss_bare, gs_bare) = small_steps
+    assert loss.tobytes() == loss_bare.tobytes()
+    assert gs.tobytes() == gs_bare.tobytes()
