@@ -153,9 +153,11 @@ def kernels_phase(seed: int = 0) -> None:
     from repro.configs import get_config
     from repro.kernels import ref
     from repro.kernels import zo_perturb as zp
+    from repro.kernels.flash_attention import flash_attention
     from repro.kernels.flash_decode import flash_decode, paged_attn_ref
     from repro.kernels.flash_prefill import flash_prefill, prefill_attn_ref
     from repro.kernels.flash_verify import flash_verify, verify_attn_ref
+    from repro.models.layers import attention
 
     cfg = get_config(ARCH)
     d, f = cfg.d_model, cfg.d_ff
@@ -179,6 +181,9 @@ def kernels_phase(seed: int = 0) -> None:
 
     def zo_ref(xx, ww, s, cc):
         return ref.zo_matmul_ref(xx.astype(f32), ww.astype(f32), s, 0, cc)
+
+    xa, ka, va = (normal(2, 512, h, hd), normal(2, 512, kvh, hd),
+                  normal(2, 512, kvh, hd))         # the train step's core
 
     n_pages = slots * n_live + 1                 # page 0 is the trash page
     kp = normal(n_pages, ps, kvh, hd)
@@ -222,6 +227,11 @@ def kernels_phase(seed: int = 0) -> None:
         "flash_prefill": paged(
             flash_prefill, prefill_attn_ref, normal(2, 64, h, hd),
             jnp.asarray([0, 192], jnp.int32), pages[:2]),
+        "flash_attention": (
+            lambda a, b, c: flash_attention(a, b, c, causal=True),
+            (xa, ka, va),
+            lambda: attention(xa.astype(f32), ka.astype(f32),
+                              va.astype(f32), causal=True)),
     }
     for name, (kernel, args, reference) in cases.items():
         compiled = jax.jit(kernel).lower(*args).compile()
@@ -271,7 +281,9 @@ def main() -> int:
     ckpt = os.path.join(WORK, "ckpt")
 
     t = timed("train", train_phase, ckpt)
-    log(f"train: losses {t['losses']}")
+    from repro import obs
+    log(f"train: losses {t['losses']} | attention cores traced, by path: "
+        f"{obs.attention_cores()}")
     s = timed("serve", serve_phase, ckpt)
     log(f"serve: {len(s['completions'])} requests | engine prefill "
         f"{s['prefill_s']!r}s, decode {s['decode_s']!r}s (first calls "

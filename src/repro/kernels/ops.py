@@ -17,7 +17,8 @@ import jax
 
 from repro.kernels import zo_perturb as _k
 
-_INTERPRET = jax.default_backend() != "tpu"
+BACKEND = jax.default_backend()
+_INTERPRET = BACKEND != "tpu"
 
 
 def paged_decode_attn(q, k_pages, v_pages, pages, pos):
@@ -54,6 +55,15 @@ def paged_prefill_attn(q, k_pages, v_pages, pages, pos):
     if _INTERPRET:
         return _fp.prefill_attn_ref(q, k_pages, v_pages, pages, pos)
     return _fp.flash_prefill(q, k_pages, v_pages, pages, pos)
+
+
+def flash_attention(q, k, v, *, causal: bool):
+    """Self-attention core as the Pallas flash kernel: compiled on TPU,
+    interpreted elsewhere. ``layers.attn_apply`` calls it where
+    :func:`repro.kernels.flash_attention.takes` finds a TPU."""
+    from repro.kernels import flash_attention as _fa
+    return _fa.flash_attention(q, k, v, causal=causal,
+                               interpret=_INTERPRET)
 
 
 def zo_add(w, seed, salt: int, coeff, dist: str = "rademacher",

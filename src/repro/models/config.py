@@ -68,10 +68,6 @@ class ModelConfig:
     # attention sequence-chunk size for memory-efficient (online-softmax)
     # attention; 0 = always use plain attention
     attn_chunk: int = 1024
-    # 'chunked' (pure-XLA scan, used by the CPU dry-run) or 'flash'
-    # (Pallas kernel, kernels/flash_attention.py -- TPU deployment;
-    # interpret-mode on CPU, so only reduced configs select it in tests)
-    attn_impl: str = "chunked"
 
     # parallelism hints
     pipeline_stages: int = 1     # PP unused for ZO (no backward) -- must be 1

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.core.perturb_ctx import sub as _sub
+from repro.kernels import flash_attention as _flash
+from repro.kernels import ops as _ops
 from repro.optim.quant import deq as _deq
 from repro.optim.quant import take_rows as _take_rows
 
@@ -212,7 +214,11 @@ def attn_project_qkv(cfg, p, x, ctx=None):
 
 def attn_apply(cfg, p, x, *, positions=None, kv_mask=None, causal=None,
                ctx=None):
-    """Self-attention over x: (B, S, D). positions: (B, S) or None."""
+    """Self-attention over x: (B, S, D). positions: (B, S) or None.
+
+    The core runs as the Pallas flash kernel where
+    :func:`repro.kernels.flash_attention.takes` says it can, else as the
+    jnp :func:`attention`."""
     b, s, _ = x.shape
     q, k, v = attn_project_qkv(cfg, p, x, ctx)
     if cfg.pos == "rope":
@@ -222,11 +228,12 @@ def attn_apply(cfg, p, x, *, positions=None, kv_mask=None, causal=None,
         q, k = apply_rope(q, cs), apply_rope(k, cs)
     causal = cfg.causal if causal is None else causal
     with jax.named_scope(obs.ATTENTION):
-        if cfg.attn_impl == "flash" and kv_mask is None:
-            from repro.kernels.flash_attention import flash_attention
-            out = flash_attention(q, k, v, causal=causal,
-                                  interpret=jax.default_backend() != "tpu")
+        if _flash.takes(q.shape, k.shape, q.dtype, causal=causal,
+                        kv_mask=kv_mask, backend=_ops.BACKEND):
+            obs.attention_core(obs.ATTN_KERNEL)
+            out = _ops.flash_attention(q, k, v, causal=causal)
         else:
+            obs.attention_core(obs.ATTN_JNP)
             out = attention(q, k, v, causal=causal, kv_mask=kv_mask,
                             chunk=cfg.attn_chunk)
     return dense(p["wo"], out.reshape(b, s, -1), _sub(ctx, "wo"))

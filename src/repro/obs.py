@@ -1,4 +1,5 @@
-"""The program's tracing layer: host spans, device scopes, a compile count.
+"""The program's tracing layer: host spans, device scopes, counts of
+compiles and of the attention core's paths.
 
 Spans are ``jax.profiler.TraceAnnotation``s named ``repro.<name>``: they
 sit on the profiler's clock beside the device trace, and exist only
@@ -26,6 +27,10 @@ LOSS = "runtime.loss"            # cross entropy over the logits
 ATTENTION = "runtime.attention"  # attention core: scores, mask, softmax, sum
 CLS_HEAD = "runtime.cls_head"    # classifier: pooling, tanh, 2-class head
 MATMUL = "zo_matmul."            # + a projection's parameter path
+
+# attention-core paths, counted as traced
+ATTN_KERNEL = "flash_attention"  # the Pallas kernel
+ATTN_JNP = "attention"           # the jnp reference path
 
 # host spans
 PREFIX = "repro."
@@ -86,3 +91,18 @@ def compiles() -> Dict[str, Tuple[int, float]]:
 
 
 jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+# attention cores, by the path each was traced onto
+_attention_cores: Dict[str, int] = collections.Counter()
+
+
+def attention_core(path: str) -> None:
+    """Count one attention core traced onto ``path`` (ATTN_KERNEL or
+    ATTN_JNP); a layer scan traces its body, and so counts, once."""
+    _attention_cores[path] += 1
+
+
+def attention_cores() -> Dict[str, int]:
+    """Attention cores traced since import, per path."""
+    return dict(_attention_cores)

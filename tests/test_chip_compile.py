@@ -1,6 +1,7 @@
 """Every main-path Pallas kernel compiles for a described TPU v5e at
 opt-1.3b widths (d_model 2048, d_ff 8192, 32 heads of 64, pages of 16),
-and the fused ZO matmul also at roberta-large's f32 widths.
+and the fused ZO matmul and the attention core also at roberta-large's
+f32 widths.
 
 Nothing runs: the TPU compiler, installed here, compiles for a chip that
 is described and not attached, and refuses what the chip would refuse
@@ -18,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import zo_perturb as zp
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.flash_verify import flash_verify
@@ -66,6 +68,16 @@ def _zo_matmul_users_q(x, w, sc, s, c):
     return zp.zo_matmul_users(x, w, s, 0, c, scale=sc)
 
 
+def _flash_causal(q, k, v):
+    return flash_attention(q, k, v, causal=True)
+
+
+def _flash_bidir_highest(q, k, v):
+    """As roberta-large runs it: f32 operands at HIGHEST, no mask."""
+    with jax.default_matmul_precision("highest"):
+        return flash_attention(q, k, v, causal=False)
+
+
 def _zo_add(w, s, c):
     return zp.zo_add(w, s, 0, c)
 
@@ -112,6 +124,11 @@ CASES = {
     "zo_add_f32": (_zo_add, [((D, F), F32), ((), U32), ((), F32)]),
     "zo_add_int8": (_zo_add_q, [((D, F), I8), ((F,), F32), ((), U32),
                                 ((), F32)]),
+    # the train cells' attention cores: opt-1.3b (batch 8 x seq 512,
+    # causal, bf16) and roberta-large (batch 64 x seq 128, f32, HIGHEST)
+    "flash_attention_opt": (_flash_causal, [((8, 512, H, HD), BF)] * 3),
+    "flash_attention_roberta_highest": (_flash_bidir_highest, [
+        ((64, 128, 16, HD), F32)] * 3),
     "flash_decode": (flash_decode, [
         ((SLOTS, H, HD), BF), ((N_PAGES, PS, KV, HD), BF),
         ((N_PAGES, PS, KV, HD), BF), ((SLOTS, N_LIVE), I32), ((SLOTS,), I32)]),

@@ -162,3 +162,25 @@ def test_attention_and_head_scopes_leave_the_step_bit_identical(
     _, (_, loss, gs), (_, loss_bare, gs_bare) = small_steps
     assert loss.tobytes() == loss_bare.tobytes()
     assert gs.tobytes() == gs_bare.tobytes()
+
+
+@pytest.mark.parametrize("backend, path", [("cpu", obs.ATTN_JNP),
+                                           ("tpu", obs.ATTN_KERNEL)])
+def test_attention_core_counter_counts_each_path_once_per_trace(
+        backend, path, monkeypatch):
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import layers as L
+
+    cfg = get_config("opt-1.3b").reduced(d_model=128, n_heads=2,
+                                         n_kv_heads=2, max_seq=128)
+    p = L.attn_init(cfg, jax.random.PRNGKey(0))
+    x = jnp.zeros((2, 128, cfg.d_model), jnp.float32)
+    monkeypatch.setattr(ops, "BACKEND", backend)
+    other = obs.ATTN_JNP if path == obs.ATTN_KERNEL else obs.ATTN_KERNEL
+    for n in (1, 2):
+        before = obs.attention_cores()
+        jax.jit(lambda p, x: L.attn_apply(cfg, p, x)).lower(p, x)
+        after = obs.attention_cores()
+        assert after.get(path, 0) - before.get(path, 0) == 1
+        assert after.get(other, 0) == before.get(other, 0)
