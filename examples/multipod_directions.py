@@ -26,7 +26,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
-from repro.core import MezoConfig, get_strategy, mezo_step_vmapdir
+from repro.core import MezoConfig, build_strategy
 from repro.data.synthetic import lm_batch_at, synthetic_lm_corpus
 from repro.models import build_model, sharding as shd
 from repro.roofline.hlo import collective_bytes
@@ -47,21 +47,24 @@ def main():
 
     mcfg = MezoConfig(eps=1e-2, lr=1e-2, n_directions=2)  # 1 per pod
 
+    # the materialized estimator: its K-way vmap axis is what shards
+    strat = build_strategy("vmapdir", "sgd")
+
+    def step(cfg, mask=None):
+        state, aux = strat.step(model.loss, strat.init_state(params, cfg),
+                                batch, jnp.uint32(0), cfg, mask)
+        return state.params, aux
+
     with jax.set_mesh(mesh):
-        strat = get_strategy("mezo-parallel")
         lowered = strat.lower(model.loss, strat.init_state(params, mcfg),
                               batch, jnp.uint32(0), mcfg, None)
         hlo = lowered.compile().as_text()
         coll = collective_bytes(hlo)
-        p2, aux = mezo_step_vmapdir(model.loss, params, batch,
-                                    jnp.uint32(0), mcfg)
+        _, aux = step(mcfg)
         # straggler: drop direction 1 (pod 1 late) -- still a valid step
-        p3, _ = mezo_step_vmapdir(model.loss, params, batch, jnp.uint32(0),
-                                  mcfg, jnp.array([1.0, 0.0]))
+        p3, _ = step(mcfg, jnp.array([1.0, 0.0]))
         # elastic: pod left -> K=1, same params sharding, no resharding
-        mcfg1 = MezoConfig(eps=1e-2, lr=1e-2, n_directions=1)
-        p4, _ = mezo_step_vmapdir(model.loss, params, batch, jnp.uint32(0),
-                                  mcfg1)
+        p4, _ = step(MezoConfig(eps=1e-2, lr=1e-2, n_directions=1))
 
     print(f"gs per direction: {np.asarray(aux.gs)}")
     print(f"collective bytes/step/device: {coll.get('total', 0):,} "

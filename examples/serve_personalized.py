@@ -30,10 +30,11 @@ USERS = {"alice": 11, "bob": 23}          # user -> private-data seed
 
 def finetune(cfg, user: str, data_seed: int, ckpt: str):
     shutil.rmtree(ckpt, ignore_errors=True)
-    # vmapdir estimator => pristine base point => the replay log is a
-    # bit-exact reconstruction of the fine-tune (walk would drift ~1e-5)
-    tc = TrainerConfig(optimizer="mezo-parallel", mezo=MZ, n_steps=30,
-                      ckpt_dir=ckpt, snapshot_every=15, log_every=10, seed=0)
+    # the replay log is a bit-exact reconstruction of the fine-tune: the
+    # estimator never writes the base point
+    tc = TrainerConfig(estimator="vmapdir", mezo=MZ, n_steps=30,
+                       ckpt_dir=ckpt, snapshot_every=15, log_every=10,
+                       seed=0)
     tr = Trainer(cfg, tc, lm_batches(8, 32, cfg.vocab, seed=data_seed))
     tr.train()
     print(f"[{user}] fine-tuned on private data: "

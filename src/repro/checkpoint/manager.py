@@ -17,7 +17,7 @@ state is step-for-step what the live run had.
 
 For the Adam baseline (no replay log possible -- gradients depend on
 data) it degrades to snapshot-only recovery, losing the steps since the
-last snapshot: this asymmetry is measured in benchmarks/table1_memory.py.
+last snapshot.
 
 Bare-params pytrees (no TrainState) are still accepted when the caller
 passes one as ``restore(like=...)``; they replay through

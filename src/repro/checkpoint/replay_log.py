@@ -6,15 +6,12 @@ and z is regenerated from the seed. So instead of flushing terabytes of
 params every N steps, we append ~(4 + 4K) bytes per step to a log and
 snapshot full params only rarely. Recovery = load nearest snapshot +
 ``repro.core.mezo.replay_update`` over the tail: memory-bandwidth-bound,
-zero forward passes. Bit-exact for the ``mezo_step_vmapdir`` path (same
-update arithmetic on pristine params); for the in-place-walk ``mezo_step``
-path, exact up to the walk's float roundoff drift (~1e-5 abs), which the
-walk itself incurs anyway.
+zero forward passes. Bit-exact: every ZO step applies the same update
+arithmetic to a base point its estimator never wrote.
 
 This is a capability *derivative-free* training gets for free and
 derivative-based training fundamentally cannot have (gradients depend on
-data); it is the fault-tolerance centerpiece of this framework
-(DESIGN.md Sec 2).
+data); it is the fault-tolerance centerpiece of this framework.
 
 Format: one JSONL line per step {"step","seed","gs","lr","eps"} -- tiny,
 append-only, human-debuggable. fsync'd per append by default.

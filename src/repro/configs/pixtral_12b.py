@@ -1,8 +1,9 @@
 """pixtral-12b [vlm]: pixtral-ViT (stub frontend) + mistral-nemo backbone.
 [hf:mistralai/Pixtral-12B-2409; unverified]
 
-The modality frontend is a STUB per the assignment: input_specs() provides
-precomputed patch embeddings (batch, num_patches, d_model)."""
+The modality frontend is a STUB: the batch carries precomputed patch
+embeddings (batch, num_patches, d_model), which ``launch/train.py`` draws
+at random."""
 from repro.models.config import ModelConfig
 
 

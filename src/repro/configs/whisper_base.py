@@ -1,5 +1,5 @@
-"""whisper-base [audio]: enc-dec; conv frontend is a STUB -- input_specs()
-provides precomputed frame embeddings. [arXiv:2212.04356; unverified]"""
+"""whisper-base [audio]: enc-dec; conv frontend is a STUB -- the batch
+carries precomputed frame embeddings. [arXiv:2212.04356; unverified]"""
 from repro.models.config import ModelConfig
 
 

@@ -1,14 +1,10 @@
 """Core: PocketLLM's derivative-free (zeroth-order) fine-tuning engine."""
 
-from repro.core.engine import (DirectionEvaluator, TrainState, UpdateRule,
-                               ZOStrategy, build_strategy, estimator_names,
-                               get_strategy, register_estimator,
-                               register_strategy, register_update_rule,
-                               strategy_names, update_rule_names)
-from repro.core.mezo import (MezoAux, MezoConfig, mezo_momentum_step,
-                             mezo_step, mezo_step_fused, mezo_step_vmapdir,
-                             momentum_history_init, replay_update,
-                             spsa_gradient_estimate)
+from repro.core.engine import (DirectionEvaluator, MezoAux, MezoConfig,
+                               TrainState, UpdateRule, ZOStrategy,
+                               build_strategy, estimator_names,
+                               momentum_history_init, update_rule_names)
+from repro.core.mezo import replay_update, spsa_gradient_estimate
 from repro.core.perturb import add_scaled_z, dot_with_z, leaf_salts
 from repro.core.perturb_ctx import PerturbCtx
 from repro.core.rng import fold_seed, gaussian_field, rademacher_field, z_field
@@ -16,11 +12,8 @@ from repro.core.rng import fold_seed, gaussian_field, rademacher_field, z_field
 __all__ = [
     "DirectionEvaluator", "MezoAux", "MezoConfig", "PerturbCtx",
     "TrainState", "UpdateRule", "ZOStrategy", "build_strategy",
-    "estimator_names", "get_strategy", "mezo_momentum_step",
-    "momentum_history_init", "mezo_step", "mezo_step_fused",
-    "mezo_step_vmapdir", "register_estimator", "register_strategy",
-    "register_update_rule", "replay_update", "spsa_gradient_estimate",
-    "strategy_names", "update_rule_names", "add_scaled_z", "dot_with_z",
-    "leaf_salts", "fold_seed", "gaussian_field", "rademacher_field",
-    "z_field",
+    "estimator_names", "momentum_history_init", "replay_update",
+    "spsa_gradient_estimate", "update_rule_names", "add_scaled_z",
+    "dot_with_z", "leaf_salts", "fold_seed", "gaussian_field",
+    "rademacher_field", "z_field",
 ]

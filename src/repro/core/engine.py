@@ -1,37 +1,39 @@
-"""Composable ZO engine: direction estimators × update rules.
+"""The ZO step: direction estimators × update rules.
 
 PocketLLM's memory claim rests on one invariant: a training step is fully
-described by the scalar pair ``(seed, gs)``. That makes the step function
-a *product* of two orthogonal choices —
+described by the scalar pair ``(seed, gs)``. The step function is built
+from two choices —
 
 * a **DirectionEvaluator** realizes ``L(theta ± eps*z_k)`` for K
-  directions and returns the projected gradients ``gs``:
+  directions and returns the projected gradients ``gs``. Neither writes
+  the base point, so the ``(seed, gs)`` replay log reconstructs a step
+  bit-exactly:
 
-  - ``walk``    — sequential in-place walk (perturb / eval /
-    counter-perturb / eval / restore), the paper-faithful memory profile;
-  - ``vmapdir`` — directions evaluated concurrently under ``vmap``
-    (one transient perturbed copy per direction, pod-shardable);
-  - ``fused``   — the perturbation never touches the parameters: a
-    :class:`~repro.core.perturb_ctx.PerturbCtx` with ``coeff=±eps`` rides
-    into the forward and dense projections compute ``X @ (W + coeff*z)``
-    via the Pallas ``zo_matmul`` kernel (0 param sweeps/direction);
+  - ``fused``   — the path every benchmark cell runs and the default: a
+    :class:`~repro.core.perturb_ctx.PerturbCtx` with ``coeff=±eps``
+    rides into the forward and dense projections compute
+    ``X @ (W + coeff*z)`` via the Pallas ``zo_matmul`` kernel (0 param
+    sweeps per direction);
+  - ``vmapdir`` — the materialized reference: directions evaluated
+    concurrently under ``vmap`` over transient ``theta ± eps*z`` copies
+    (pod-shardable);
 
 * an **UpdateRule** turns ``(seed, gs)`` into a parameter update:
 
-  - ``sgd``      — the shared f32 seed-replay tail
-    ``theta -= lr * sum_k coeffs_k * gs_k * z_k``;
-  - ``momentum`` — ZO momentum via *truncated seed replay*: classical
+  - ``sgd``       — the shared f32 seed-replay tail
+    ``theta -= lr * sum_k coeffs_k * gs_k * z_k`` (the default);
+  - ``momentum``  — ZO momentum via *truncated seed replay*: classical
     momentum needs a param-sized velocity buffer (exactly the memory MeZO
     exists to avoid), but the ZO velocity is structurally
     ``v_t = sum_i beta^{t-i} g_i z_i``, so a window of M
     ``(seed, gs, coeffs)`` rows represents it in O(M*K) scalars and the
-    update replays the window with geometric weights.
+    update replays the window with geometric weights;
+  - ``stale-sgd`` — sgd scaled by ``decay ** staleness``, the async
+    fleet's rule.
 
-Every estimator×update combination shares the same f32 update arithmetic
+Every estimator×update pairing shares the same f32 update arithmetic
 (:func:`_direction_coeffs` / :func:`_apply_direction_updates`), which is
-what keeps the ``(seed, gs)`` replay log interchangeable across
-strategies — bit-exact for the pristine-base-point estimators
-(``vmapdir``, ``fused``), and up to walk roundoff drift for ``walk``.
+what keeps the ``(seed, gs)`` replay log interchangeable across them.
 
 The engine also owns:
 
@@ -39,13 +41,11 @@ The engine also owns:
   (params, step counter, update-rule state). The checkpoint manager
   snapshots/restores it whole, so momentum history and Adam moments
   survive a crash (``checkpoint/manager.py``).
-* a name-based **strategy registry** (builder pattern): the trainer and
-  CLI resolve ``--estimator fused --update momentum`` (or a legacy alias
-  like ``"mezo-fused"``) through :func:`build_strategy` /
-  :func:`get_strategy` instead of a hand-written dict.
+* :func:`build_strategy` — the trainer and CLI resolve
+  ``--estimator fused --update momentum`` through it, from two
+  module-level tables of estimators and update rules.
 * :meth:`ZOStrategy.run_chunk` — a multi-step ``lax.scan`` over a stacked
-  batch pytree that amortizes per-step dispatch overhead
-  (``benchmarks/table2_walltime.py``'s chunked arm).
+  batch pytree that amortizes per-step dispatch overhead.
 """
 
 from __future__ import annotations
@@ -220,23 +220,18 @@ def _decay(params, wd_coeff):
 class DirectionEvaluator:
     """How ``theta ± eps*z`` is realized for the 2K loss evaluations.
 
-    eval_fn: (loss_fn, params, batch, seed, cfg, eps=None)
-    -> (params, gs, ls). ``params`` is threaded through because the
-    in-place walk mutates (and restores) it; pristine evaluators return
-    it untouched. ``eps`` optionally overrides ``cfg.eps`` with a traced
-    f32 scalar — the jitted steps always pass it so the projected
-    gradient ``(l+ - l-) / (2 eps)`` is a true division for constant and
-    traced eps alike (XLA rewrites division by a *baked* constant into
-    multiply-by-reciprocal, which would fork the last ulp between the
-    sequential and user-batched paths).
+    eval_fn: (loss_fn, params, batch, seed, cfg, eps=None) -> (gs, ls).
+    ``params`` is read, never written. ``eps`` optionally overrides
+    ``cfg.eps`` with a traced f32 scalar — the jitted steps always pass
+    it so the projected gradient ``(l+ - l-) / (2 eps)`` is a true
+    division for constant and traced eps alike (XLA rewrites division by
+    a *baked* constant into multiply-by-reciprocal, which would fork the
+    last ulp between the sequential and user-batched paths).
 
-    pristine: the base point is never written during evaluation, so the
-    (seed, gs) replay log reconstructs the step bit-exactly.
     donate: the step jit may donate the input TrainState's buffers.
     """
     name: str
-    eval_fn: Callable[..., Tuple[PyTree, jnp.ndarray, jnp.ndarray]]
-    pristine: bool
+    eval_fn: Callable[..., Tuple[jnp.ndarray, jnp.ndarray]]
     donate: bool
 
 
@@ -244,29 +239,6 @@ def _f32(value, default: float):
     """Traced-or-config f32 scalar (``None`` -> the config constant)."""
     return jnp.float32(default) if value is None \
         else jnp.asarray(value, jnp.float32)
-
-
-def _eval_walk(loss_fn: LossFn, params: PyTree, batch: Any, seed,
-               cfg: MezoConfig, eps=None):
-    """Sequential in-place walk: peak memory = params + one forward."""
-    eps = _f32(eps, cfg.eps)
-
-    def one_dir(p, k):
-        s = zrng.fold_seed(seed, k)
-        p = add_scaled_z(p, s, eps, dist=cfg.dist, use_kernel=cfg.use_kernel)
-        with jax.named_scope(obs.FORWARD):
-            lp = loss_fn(p, batch)
-        p = add_scaled_z(p, s, -2.0 * eps, dist=cfg.dist,
-                         use_kernel=cfg.use_kernel)
-        with jax.named_scope(obs.FORWARD):
-            lm = loss_fn(p, batch)
-        # restore to base point for the next direction
-        p = add_scaled_z(p, s, eps, dist=cfg.dist, use_kernel=cfg.use_kernel)
-        return p, ((lp - lm) / (2.0 * eps), 0.5 * (lp + lm))
-
-    params, (gs, ls) = jax.lax.scan(
-        one_dir, params, jnp.arange(cfg.n_directions, dtype=jnp.uint32))
-    return params, gs, ls
 
 
 def _eval_vmapdir(loss_fn: LossFn, params: PyTree, batch: Any, seed,
@@ -286,9 +258,7 @@ def _eval_vmapdir(loss_fn: LossFn, params: PyTree, batch: Any, seed,
             lm = loss_fn(pm, batch)
         return (lp - lm) / (2.0 * eps), 0.5 * (lp + lm)
 
-    gs, ls = jax.vmap(eval_dir)(
-        jnp.arange(cfg.n_directions, dtype=jnp.uint32))
-    return params, gs, ls
+    return jax.vmap(eval_dir)(jnp.arange(cfg.n_directions, dtype=jnp.uint32))
 
 
 def _eval_fused(loss_fn: LossFn, params: PyTree, batch: Any, seed,
@@ -312,7 +282,7 @@ def _eval_fused(loss_fn: LossFn, params: PyTree, batch: Any, seed,
 
     _, (gs, ls) = jax.lax.scan(one_dir, None,
                                jnp.arange(cfg.n_directions, dtype=jnp.uint32))
-    return params, gs, ls
+    return gs, ls
 
 
 # ---------------------------------------------------------------------------
@@ -438,11 +408,11 @@ def _step_body(strategy: "ZOStrategy", loss_fn: LossFn, state: TrainState,
                batch: Any, seed, cfg: MezoConfig, direction_mask,
                eps=None, lr=None):
     seed = jnp.asarray(seed, jnp.uint32)
-    params, gs, ls = strategy.estimator.eval_fn(
+    gs, ls = strategy.estimator.eval_fn(
         loss_fn, state.params, batch, seed, cfg, eps=eps)
     with jax.named_scope(obs.UPDATE):
         params, opt = strategy.update.update_fn(
-            params, state.opt, seed, gs, direction_mask, cfg, lr=lr)
+            state.params, state.opt, seed, gs, direction_mask, cfg, lr=lr)
     aux = MezoAux(loss=ls.mean(), gs=gs, seed=seed,
                   grad_norm_est=jnp.abs(gs).mean())
     return TrainState(params=params, step=state.step + jnp.uint32(1),
@@ -575,17 +545,7 @@ class ZOStrategy:
         lanes come back bit-identical (masked merge), active lanes
         bit-identical to a lone sequential :meth:`step` with the same
         (seed, eps, lr).
-
-        Requires a pristine estimator (``fused`` / ``vmapdir``): the
-        walk's in-place sweeps would accumulate roundoff per lane and
-        break the replay-log contract the engine's eviction/resume
-        machinery rests on.
         """
-        if not self.estimator.pristine:
-            raise ValueError(
-                f"step_users requires a pristine direction estimator "
-                f"(got {self.estimator.name!r}): in-place walk roundoff "
-                f"would break per-user replay-log bit-parity")
         from repro.core.batching import AxesSpec, user_state_axes
         u = seeds.shape[0]
         eps = jnp.broadcast_to(_f32(eps, cfg.eps), (u,))
@@ -597,28 +557,23 @@ class ZOStrategy:
 
 
 # ---------------------------------------------------------------------------
-# the strategy registry (builder pattern: names -> composed strategies)
+# the estimator and update-rule tables
 
 
-_ESTIMATORS: Dict[str, DirectionEvaluator] = {}
-_UPDATE_RULES: Dict[str, UpdateRule] = {}
-_STRATEGY_ALIASES: Dict[str, Tuple[str, str]] = {}
+SGD = UpdateRule(name="sgd", init_fn=_sgd_init, update_fn=_sgd_update)
+STALE_SGD = UpdateRule(name="stale-sgd", init_fn=_sgd_init,
+                       update_fn=_stale_sgd_update)
+
+_ESTIMATORS: Dict[str, DirectionEvaluator] = {e.name: e for e in (
+    DirectionEvaluator(name="fused", eval_fn=_eval_fused, donate=True),
+    DirectionEvaluator(name="vmapdir", eval_fn=_eval_vmapdir, donate=False),
+)}
+_UPDATE_RULES: Dict[str, UpdateRule] = {u.name: u for u in (
+    SGD, STALE_SGD,
+    UpdateRule(name="momentum", init_fn=momentum_history_init,
+               update_fn=_momentum_update),
+)}
 _STRATEGY_CACHE: Dict[Tuple[str, str], ZOStrategy] = {}
-
-
-def register_estimator(e: DirectionEvaluator) -> DirectionEvaluator:
-    _ESTIMATORS[e.name] = e
-    return e
-
-
-def register_update_rule(u: UpdateRule) -> UpdateRule:
-    _UPDATE_RULES[u.name] = u
-    return u
-
-
-def register_strategy(name: str, estimator: str, update: str) -> None:
-    """Bind a short name (e.g. ``"mezo-fused"``) to a pairing."""
-    _STRATEGY_ALIASES[name] = (estimator, update)
 
 
 def estimator_names():
@@ -629,13 +584,9 @@ def update_rule_names():
     return sorted(_UPDATE_RULES)
 
 
-def strategy_names():
-    return sorted(_STRATEGY_ALIASES)
-
-
-def build_strategy(estimator: str = "walk", update: str = "sgd"
+def build_strategy(estimator: str = "fused", update: str = "sgd"
                    ) -> ZOStrategy:
-    """Compose any estimator×update pairing by name (cached singletons,
+    """Compose an estimator×update pairing by name (cached singletons,
     so jit caches keyed on the strategy stay warm)."""
     if estimator not in _ESTIMATORS:
         raise ValueError(
@@ -650,36 +601,3 @@ def build_strategy(estimator: str = "walk", update: str = "sgd"
         _STRATEGY_CACHE[key] = ZOStrategy(
             estimator=_ESTIMATORS[estimator], update=_UPDATE_RULES[update])
     return _STRATEGY_CACHE[key]
-
-
-def get_strategy(name: str) -> ZOStrategy:
-    """Resolve a registered strategy name (legacy ``--optimizer`` values)."""
-    if name not in _STRATEGY_ALIASES:
-        raise ValueError(
-            f"unknown ZO strategy {name!r}; registered strategies: "
-            f"{strategy_names()} (any estimator×update pairing is "
-            f"constructible via build_strategy: {estimator_names()} × "
-            f"{update_rule_names()})")
-    return build_strategy(*_STRATEGY_ALIASES[name])
-
-
-WALK = register_estimator(DirectionEvaluator(
-    name="walk", eval_fn=_eval_walk, pristine=False, donate=True))
-VMAPDIR = register_estimator(DirectionEvaluator(
-    name="vmapdir", eval_fn=_eval_vmapdir, pristine=True, donate=False))
-FUSED = register_estimator(DirectionEvaluator(
-    name="fused", eval_fn=_eval_fused, pristine=True, donate=True))
-
-SGD = register_update_rule(UpdateRule(
-    name="sgd", init_fn=_sgd_init, update_fn=_sgd_update))
-STALE_SGD = register_update_rule(UpdateRule(
-    name="stale-sgd", init_fn=_sgd_init, update_fn=_stale_sgd_update))
-MOMENTUM = register_update_rule(UpdateRule(
-    name="momentum", init_fn=momentum_history_init,
-    update_fn=_momentum_update))
-
-register_strategy("mezo", "walk", "sgd")
-register_strategy("mezo-parallel", "vmapdir", "sgd")
-register_strategy("mezo-fused", "fused", "sgd")
-register_strategy("mezo-momentum", "vmapdir", "momentum")
-register_strategy("mezo-fused-momentum", "fused", "momentum")

@@ -8,22 +8,12 @@ Implements SPSA (Spall 1992) with MeZO's seed-replay storage trick
     g  = (l+ - l-) / (2 eps)
     theta <- theta - lr * g * z
 
-The step machinery itself lives in :mod:`repro.core.engine` as a
-composable estimator×update strategy matrix; this module keeps the
-historical step-function entry points as thin wrappers over registered
-strategies, plus the standalone replay / analysis helpers:
-
-* ``mezo_step``         -> strategy ``walk + sgd``    ("mezo")
-* ``mezo_step_vmapdir`` -> strategy ``vmapdir + sgd`` ("mezo-parallel")
-* ``mezo_step_fused``   -> strategy ``fused + sgd``   ("mezo-fused")
-* ``mezo_momentum_step``-> strategy ``vmapdir + momentum``
-
-All return the new params plus a :class:`MezoAux` record whose
-``(seed, gs)`` pair is exactly what the replay-log checkpointer persists
-(~12 bytes/step/direction) -- see repro/checkpoint/replay_log.py. Every
-strategy shares the engine's f32 update tail, so the replay log is
-interchangeable across them (bit-exact for the pristine-base-point
-estimators ``vmapdir`` / ``fused``).
+The step itself is :func:`repro.core.engine.build_strategy`'s
+estimator×update pairing (``init_state`` / ``step``). Its
+:class:`MezoAux` record's ``(seed, gs)`` pair is exactly what the
+replay-log checkpointer persists (~12 bytes/step/direction) -- see
+repro/checkpoint/replay_log.py. This module keeps the standalone replay
+and analysis helpers.
 """
 
 from __future__ import annotations
@@ -34,67 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import rng as zrng
-from repro.core.engine import (  # noqa: F401  (re-exported back-compat API)
-    MezoAux, MezoConfig, TrainState, _apply_direction_updates, _decay,
-    _direction_coeffs, build_strategy, get_strategy, momentum_history_init)
-from repro.core.engine import LossFn, PyTree, SGD
+from repro.core.engine import LossFn, MezoConfig, PyTree, SGD
 from repro.core.perturb import add_scaled_z
-
-
-def mezo_step(loss_fn: LossFn, params: PyTree, batch: Any, seed,
-              cfg: MezoConfig, direction_mask=None):
-    """Paper-faithful sequential MeZO step (in-place walk, donated params).
-
-    direction_mask: optional (K,) 0/1 floats -- straggler mitigation drops
-    late directions; the update renormalizes over survivors (an unbiased
-    lower-sample SPSA estimate, unique to ZO: no gradient shard is lost).
-    """
-    strat = get_strategy("mezo")
-    state, aux = strat.step(loss_fn, strat.init_state(params, cfg), batch,
-                            seed, cfg, direction_mask)
-    return state.params, aux
-
-
-def mezo_step_vmapdir(loss_fn: LossFn, params: PyTree, batch: Any, seed,
-                      cfg: MezoConfig, direction_mask=None):
-    """Direction-parallel MeZO step (strategy ``vmapdir + sgd``)."""
-    strat = get_strategy("mezo-parallel")
-    state, aux = strat.step(loss_fn, strat.init_state(params, cfg), batch,
-                            seed, cfg, direction_mask)
-    return state.params, aux
-
-
-def mezo_step_fused(loss_fn: LossFn, params: PyTree, batch: Any, seed,
-                    cfg: MezoConfig, direction_mask=None):
-    """Fused perturbed-forward MeZO step: 0 param sweeps per direction.
-
-    ``loss_fn`` must accept a ``perturb=`` keyword (models built by
-    repro.models.build_model do).
-    """
-    strat = get_strategy("mezo-fused")
-    state, aux = strat.step(loss_fn, strat.init_state(params, cfg), batch,
-                            seed, cfg, direction_mask)
-    return state.params, aux
-
-
-def mezo_momentum_step(loss_fn: LossFn, params: PyTree, batch: Any, seed,
-                       cfg: MezoConfig, hist):
-    """ZO-momentum step (strategy ``vmapdir + momentum``).
-
-    hist: the truncated seed-replay window from
-    :func:`momentum_history_init` (or the previous call's return).
-    Returns (params, aux, new_hist). Pre-engine histories without the
-    per-entry ``coeffs`` row are upgraded with the ``-lr/K`` coefficient
-    the old step function applied to every row (g=0 rows stay no-ops).
-    """
-    if "coeffs" not in hist:
-        kk = hist["gs"].shape[1]
-        hist = dict(hist, coeffs=jnp.full_like(
-            hist["gs"], -jnp.float32(cfg.lr) / kk))
-    strat = build_strategy("vmapdir", "momentum")
-    state = TrainState(params=params, step=jnp.uint32(0), opt=hist)
-    state, aux = strat.step(loss_fn, state, batch, seed, cfg)
-    return state.params, aux, state.opt
 
 
 def replay_update(params: PyTree, seed, gs, cfg: MezoConfig,
@@ -105,10 +36,9 @@ def replay_update(params: PyTree, seed, gs, cfg: MezoConfig,
     worker reconstructs theta_t from theta_0 and the scalar log at memory
     bandwidth, with zero forward passes. It *is* the engine's sgd update
     rule -- identical f32 arithmetic to the live step (including the f32
-    ``lr * weight_decay`` coefficient), hence bit-exact replay for the
-    pristine-base-point estimators. ``direction_mask`` is the logged
-    straggler mask of the step, so replay renormalizes over the same
-    surviving directions.
+    ``lr * weight_decay`` coefficient), hence bit-exact replay.
+    ``direction_mask`` is the logged straggler mask of the step, so
+    replay renormalizes over the same surviving directions.
     """
     params, _ = SGD.update_fn(params, {}, seed, gs, direction_mask, cfg)
     return params

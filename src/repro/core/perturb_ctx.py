@@ -1,9 +1,8 @@
 """Perturbed-forward execution context (the fused MeZO path).
 
-The sequential ``mezo_step`` realizes theta ± eps*z with three full
-parameter sweeps per direction (perturb / counter-perturb / restore), and
-``mezo_step_vmapdir`` with one transient param-sized copy. The fused path
-removes both: the *unperturbed* params flow into the forward together with
+The materialized ``vmapdir`` estimator realizes theta ± eps*z with one
+transient param-sized copy per side. The fused path removes it: the
+*unperturbed* params flow into the forward together with
 a :class:`PerturbCtx` carrying ``(seed, coeff, dist)``, and each consumer
 applies its leaf's perturbation at the point of use --
 
@@ -260,7 +259,7 @@ class PerturbCtx:
         inside sort-based dispatch) -- and, scoped at the root, the
         parity oracle the tests evaluate the fused forward against.
         Equivalent to ``add_scaled_z`` restricted to the subtree: one
-        transient copy of the subtree, no walk sweeps.
+        transient copy of the subtree.
         """
         ctx = self.scope(name) if name else self
         leaves, treedef = jax.tree_util.tree_flatten_with_path(

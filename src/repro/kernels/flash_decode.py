@@ -3,8 +3,8 @@
 Decode is single-token attention: one query row per sequence against
 everything that sequence has cached. The dense path reads the full
 (B, S_max) cache every step -- including the dead tail beyond each
-slot's position -- and its HBM traffic is what caps decode tok/s
-(table3). This kernel reads K/V as fixed-size *pages* gathered through a
+slot's position -- and its HBM traffic is what caps decode tok/s.
+This kernel reads K/V as fixed-size *pages* gathered through a
 per-slot page table, splits the key axis across a grid dimension, and
 reduces with online-softmax partials (acc, m, l) in VMEM scratch:
 

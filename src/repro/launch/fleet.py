@@ -48,10 +48,9 @@ def build_argparser() -> argparse.ArgumentParser:
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--seq", type=int, default=16)
     ap.add_argument("--estimator", default="fused",
-                    choices=[e for e in estimator_names() if e != "walk"],
-                    help="pristine direction evaluator (leased params "
-                         "snapshots are shared by reference; the in-place "
-                         "walk would corrupt them)")
+                    choices=estimator_names(),
+                    help="direction evaluator (leased params snapshots "
+                         "are shared by reference and never written)")
     ap.add_argument("--eps", type=float, default=1e-3)
     ap.add_argument("--lr", type=float, default=1e-4)
     ap.add_argument("--directions", type=int, default=2)

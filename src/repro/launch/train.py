@@ -7,12 +7,13 @@ device (examples/quickstart.py) -- same code path, smaller shapes.
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --reduced \
       --optimizer mezo --steps 200 --batch 8 --seq 64
 
-The training strategy is resolved from the core engine's registry:
-``--optimizer`` names a registered strategy (or ``adam``), while
-``--estimator`` / ``--update`` compose any pairing from the
-estimator×update matrix directly, e.g.
+``--optimizer mezo`` (the default) trains with the ZO step
+``build_strategy(--estimator, --update)``, fused + sgd unless the flags
+say otherwise, e.g.
 
   ... --estimator fused --update momentum --momentum 0.9
+
+``--optimizer adam`` runs the gradient baseline.
 """
 
 from __future__ import annotations
@@ -25,8 +26,7 @@ import os
 import numpy as np
 
 from repro.configs import ALL_ARCHS, get_config
-from repro.core.engine import (estimator_names, strategy_names,
-                               update_rule_names)
+from repro.core.engine import estimator_names, update_rule_names
 from repro.core.mezo import MezoConfig
 from repro.data.synthetic import lm_batches, sst2_batches
 from repro.launch import compile_cache
@@ -86,16 +86,15 @@ def build_argparser() -> argparse.ArgumentParser:
     ap.add_argument("--arch", default="opt-1.3b", choices=ALL_ARCHS)
     ap.add_argument("--reduced", action="store_true",
                     help="CPU-sized config of the same family")
-    ap.add_argument("--optimizer", default="mezo",
-                    choices=strategy_names() + ["adam"],
-                    help="registered strategy name, or adam (gradient "
-                         "baseline)")
-    ap.add_argument("--estimator", default=None,
+    ap.add_argument("--optimizer", default="mezo", choices=["mezo", "adam"],
+                    help="mezo (the ZO step) or adam (gradient baseline)")
+    ap.add_argument("--estimator", default="fused",
                     choices=estimator_names(),
-                    help="direction evaluator; with --update, composes any "
-                         "estimator×update pairing (overrides --optimizer)")
-    ap.add_argument("--update", default=None, choices=update_rule_names(),
-                    help="update rule applied to the (seed, gs) estimate")
+                    help="ZO direction evaluator (vmapdir: the "
+                         "materialized reference)")
+    ap.add_argument("--update", default="sgd", choices=update_rule_names(),
+                    help="ZO update rule applied to the (seed, gs) "
+                         "estimate")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -120,9 +119,9 @@ def build_argparser() -> argparse.ArgumentParser:
                          "(unknown modes raise with the supported list)")
     ap.add_argument("--use-kernel", action="store_true",
                     help="route MXU-aligned leaves/projections through the "
-                         "Pallas ZO kernels (zo_add, and zo_matmul for "
-                         "mezo-fused). TPU-oriented: on CPU the kernels run "
-                         "in slow interpret mode")
+                         "Pallas ZO kernels (zo_add, and zo_matmul for the "
+                         "fused estimator). TPU-oriented: on CPU the kernels "
+                         "run in slow interpret mode")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--snapshot-every", type=int, default=100)

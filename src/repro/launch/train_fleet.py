@@ -55,9 +55,7 @@ def build_argparser() -> argparse.ArgumentParser:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=32)
     ap.add_argument("--estimator", default="fused",
-                    choices=[e for e in estimator_names() if e != "walk"],
-                    help="pristine direction evaluator (the in-place walk "
-                         "cannot give replay-log bit-parity)")
+                    choices=estimator_names(), help="direction evaluator")
     ap.add_argument("--update", default="sgd", choices=update_rule_names())
     ap.add_argument("--eps", type=float, default=1e-3)
     ap.add_argument("--lr", type=float, default=1e-4)

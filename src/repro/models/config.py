@@ -79,7 +79,7 @@ class ModelConfig:
     def __post_init__(self):
         assert self.pipeline_stages == 1, (
             "PP is deliberately unsupported: ZO training has no backward "
-            "pass, so pipeline bubbles buy nothing (DESIGN.md Sec 4)")
+            "pass, so pipeline bubbles buy nothing")
 
     @property
     def resolved_head_dim(self) -> int:

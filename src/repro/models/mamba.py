@@ -1,8 +1,8 @@
 """Mamba (selective SSM) layer -- the recurrent sublayer of jamba.
 
 Forward-only selective scan via ``lax.scan`` over time (ZO fine-tuning
-never backprops through the scan, so no remat policy is needed -- see
-DESIGN.md Sec 5). Decode carries (conv_state, ssm_state) explicitly.
+never backprops through the scan, so no remat policy is needed).
+Decode carries (conv_state, ssm_state) explicitly.
 
 The full-sequence apply threads an optional ``PerturbCtx``: every weight
 use applies ``coeff*z`` in place (dense projections via ``ctx``-aware

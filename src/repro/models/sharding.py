@@ -11,7 +11,7 @@ Megatron-style tensor parallelism over the ``model`` axis:
 
 Params are replicated across ``pod`` and ``data`` (ZO direction
 parallelism needs no param sharding across pods -- cross-pod traffic is
-scalars only; see DESIGN.md Sec 4).
+scalars only).
 
 Rules are matched on the flattened path string, most-specific-first.
 ``spec_tree(params_shape_tree)`` returns a PartitionSpec pytree suitable

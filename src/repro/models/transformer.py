@@ -265,7 +265,7 @@ def build_model(cfg: ModelConfig) -> Model:
     the same config shares ONE Model instance, so the jitted serving /
     decode entry points traced against its bound functions hit the
     compilation cache across engines instead of re-tracing per engine
-    (the dominant cost of the pre-paging decode baseline -- table3)."""
+    (the dominant cost of the pre-paging decode baseline)."""
     plan = build_plan(cfg)
     dtype = L._dt(cfg)
     init = partial(_INITS[cfg.family], cfg)

@@ -308,10 +308,9 @@ def tree_is_quantized(tree: PyTree) -> bool:
 
 
 def quantized_bytes(tree: PyTree):
-    """(resident_bytes, f32_equivalent_bytes) of a param tree -- the
-    table-1 quant arm's accounting. Resident counts int8 values + f32
-    scales (+ deltas if attached); the f32 equivalent counts every
-    floating leaf at 4 bytes/element."""
+    """(resident_bytes, f32_equivalent_bytes) of a param tree. Resident
+    counts int8 values + f32 scales (+ deltas if attached); the f32
+    equivalent counts every floating leaf at 4 bytes/element."""
     resident = f32_eq = 0
     for leaf in jax.tree_util.tree_leaves(tree, is_leaf=is_quantized):
         if is_quantized(leaf):

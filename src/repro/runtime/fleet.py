@@ -379,10 +379,9 @@ class _SimWorker:
 @partial(jax.jit, static_argnames=("loss_fn", "cfg", "eval_fn"))
 def _jit_eval(loss_fn, params, batch, seed, cfg, eval_fn):
     """One direction lease's device work: K perturbed-forward pairs ->
-    ((K,) gs, mean loss). ``eval_fn`` is a pristine DirectionEvaluator's
-    eval_fn -- the snapshot params are shared by reference and must
-    never be written."""
-    _, gs, ls = eval_fn(loss_fn, params, batch, seed, cfg)
+    ((K,) gs, mean loss). ``eval_fn`` is a DirectionEvaluator's eval_fn,
+    which never writes the snapshot params it shares by reference."""
+    gs, ls = eval_fn(loss_fn, params, batch, seed, cfg)
     return gs, ls.mean()
 
 
@@ -415,14 +414,8 @@ class FleetSim:
 
         if not workers:
             raise ValueError("FleetSim needs at least one worker")
-        strat = build_strategy(estimator, "stale-sgd")
-        if not strat.estimator.pristine:
-            raise ValueError(
-                f"fleet workers share params snapshots by reference and "
-                f"need a pristine direction estimator (vmapdir/fused), "
-                f"got {estimator!r}: the in-place walk would corrupt "
-                f"co-leased snapshots")
-        self._eval_fn = strat.estimator.eval_fn
+        self._eval_fn = build_strategy(estimator,
+                                       "stale-sgd").estimator.eval_fn
         self.model_cfg = model_cfg
         self.model = build_model(model_cfg)
         self.cfg = mezo_cfg or MezoConfig()

@@ -7,11 +7,10 @@ deliberately dumb -- all cleverness lives in core/ and checkpoint/ -- so
 its failure behavior is auditable: any crash between two ``on_step``
 calls loses at most the step in flight.
 
-Strategy resolution: ``TrainerConfig.optimizer`` names a registered
-strategy ("mezo", "mezo-parallel", "mezo-fused", "mezo-momentum", ...)
-or "adam" for the gradient baseline; setting ``estimator`` / ``update``
-composes any pairing from the engine's estimator×update matrix directly
-(e.g. estimator="fused", update="momentum").
+Strategy resolution: ``TrainerConfig.optimizer`` is "mezo" (the ZO
+arm) or "adam" (the gradient baseline). The ZO arm's step is
+``build_strategy(estimator, update)``, by default ``fused`` + ``sgd``:
+the step every benchmark cell runs.
 """
 
 from __future__ import annotations
@@ -26,8 +25,7 @@ import jax.numpy as jnp
 from repro import obs
 from repro.checkpoint.manager import CheckpointManager
 from repro.core import rng as zrng
-from repro.core.engine import (TrainState, build_strategy, get_strategy,
-                               strategy_names)
+from repro.core.engine import TrainState, build_strategy
 from repro.core.mezo import MezoConfig
 from repro.models import build_model
 from repro.models.config import ModelConfig
@@ -41,9 +39,9 @@ PyTree = Any
 
 @dataclasses.dataclass(frozen=True)
 class TrainerConfig:
-    optimizer: str = "mezo"          # registered strategy name | adam
-    estimator: Optional[str] = None  # walk | vmapdir | fused (overrides
-    update: Optional[str] = None     # sgd | momentum        .. optimizer)
+    optimizer: str = "mezo"          # mezo (ZO) | adam
+    estimator: str = "fused"         # ZO only: fused | vmapdir
+    update: str = "sgd"              # ZO only: sgd | momentum | stale-sgd
     mezo: MezoConfig = MezoConfig()
     adam: AdamConfig = AdamConfig()
     quant: str = "none"              # base-weight quantization: none | int8
@@ -67,25 +65,18 @@ class Trainer:
                 "baseline differentiates through the weights, but an "
                 "int8 base is frozen (updates live in the f32 delta, "
                 "written by seed replay)")
-        if train_cfg.optimizer == "adam":
-            if train_cfg.estimator or train_cfg.update:
-                raise ValueError(
-                    "TrainerConfig.estimator/.update compose ZO strategies "
-                    "and cannot be combined with optimizer='adam' (the "
-                    "gradient baseline has no estimator×update axes)")
-        else:
-            if train_cfg.estimator or train_cfg.update:
-                self.strategy = build_strategy(
-                    train_cfg.estimator or "walk", train_cfg.update or "sgd")
-            elif train_cfg.optimizer not in strategy_names():
-                raise ValueError(
-                    f"unknown TrainerConfig.optimizer "
-                    f"{train_cfg.optimizer!r}; registered strategies: "
-                    f"{strategy_names() + ['adam']} (or compose any "
-                    f"estimator×update pairing via TrainerConfig.estimator"
-                    f"/.update)")
-            else:
-                self.strategy = get_strategy(train_cfg.optimizer)
+        if train_cfg.optimizer == "mezo":
+            self.strategy = build_strategy(train_cfg.estimator,
+                                           train_cfg.update)
+        elif train_cfg.optimizer != "adam":
+            raise ValueError(
+                f"unknown TrainerConfig.optimizer {train_cfg.optimizer!r}; "
+                f"expected one of ['mezo', 'adam']")
+        elif (train_cfg.estimator, train_cfg.update) != ("fused", "sgd"):
+            raise ValueError(
+                "TrainerConfig.estimator/.update compose the ZO step and "
+                "cannot be combined with optimizer='adam' (the gradient "
+                "baseline has no estimator×update axes)")
 
         self.mcfg = model_cfg
         self.tcfg = train_cfg

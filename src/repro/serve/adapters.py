@@ -20,9 +20,8 @@ thousands of users' fine-tunes next to a single copy of the base model.
   enough that replay latency matters more than bit-exactness.
 
 Materializing from records is bit-identical to
-``CheckpointManager.restore`` for the pristine-base-point estimators
-(vmapdir / fused); the int8 delta form is lossy by one quantization
-roundtrip per leaf.
+``CheckpointManager.restore``; the int8 delta form is lossy by one
+quantization roundtrip per leaf.
 
 The shared base may itself be an int8 *quantized* base
 (``optim.quant.quantize_tree``): replay then writes each quantized

@@ -69,7 +69,7 @@ per Model (see ``_serving_fns``): constructing an engine re-uses the
 compiled decode/prefill/install executables instead of re-tracing them,
 which -- together with keeping the sampler's key-split off the
 greedy-only hot path -- is where the pre-paging decode baseline lost
-most of its step budget (table3).
+most of its step budget.
 
 Speculative decoding (``spec_k``, paged mode only): the engine's own
 frozen base weights (``store.materialize(None)`` -- the int8 base when

@@ -133,11 +133,6 @@ class TrainEngine:
         self.store = store
         self.mz = mezo_cfg or store.cfg
         self.strategy = build_strategy(estimator, update)
-        if not self.strategy.estimator.pristine:
-            raise ValueError(
-                f"TrainEngine requires a pristine direction estimator "
-                f"(vmapdir/fused), got {estimator!r}: the in-place walk's "
-                f"roundoff would break replay-log bit-parity on resume")
         if self.strategy.update.name != store.rule.name:
             raise ValueError(
                 f"engine update rule {self.strategy.update.name!r} != "

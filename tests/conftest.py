@@ -26,3 +26,21 @@ def _drop_jax_executables():
 
     jax.clear_caches()
     gc.collect()
+
+
+@pytest.fixture
+def zo_step():
+    """One sgd ZO step from bare params, through ``build_strategy``:
+    ``zo_step(loss_fn, params, batch, seed, cfg, mask=None,
+    estimator="fused") -> (params, aux)``. The fused estimator donates
+    ``params``."""
+    from repro.core import build_strategy
+
+    def step(loss_fn, params, batch, seed, cfg, mask=None,
+             estimator="fused"):
+        strat = build_strategy(estimator, "sgd")
+        state, aux = strat.step(loss_fn, strat.init_state(params, cfg),
+                                batch, seed, cfg, mask)
+        return state.params, aux
+
+    return step

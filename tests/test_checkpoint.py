@@ -11,7 +11,7 @@ import pytest
 from repro.checkpoint import (CheckpointManager, ReplayLog, latest_step,
                               load_params, save_params)
 from repro.checkpoint.replay_log import replay_into
-from repro.core import MezoConfig, mezo_step_vmapdir
+from repro.core import MezoConfig
 
 
 def _params(key=0):
@@ -94,7 +94,7 @@ def test_replay_log_dedup(tmp_path):
     assert len(ReplayLog.read(path)) == 1
 
 
-def test_replay_into_matches_live_update(tmp_path):
+def test_replay_into_matches_live_update(tmp_path, zo_step):
     params = _params(1)
 
     def loss_fn(p, _):
@@ -104,8 +104,8 @@ def test_replay_into_matches_live_update(tmp_path):
     p_live = jax.tree.map(jnp.copy, params)
     recs = []
     for t in range(5):
-        p_live, aux = mezo_step_vmapdir(loss_fn, p_live, None,
-                                        jnp.uint32(t), cfg)
+        p_live, aux = zo_step(loss_fn, p_live, None, jnp.uint32(t), cfg,
+                              estimator="vmapdir")
         recs.append({"step": t, "seed": int(aux.seed),
                      "gs": np.asarray(aux.gs).tolist(),
                      "lr": cfg.lr, "eps": cfg.eps})
@@ -115,7 +115,7 @@ def test_replay_into_matches_live_update(tmp_path):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-7)
 
 
-def test_manager_restore_snapshot_plus_log(tmp_path):
+def test_manager_restore_snapshot_plus_log(tmp_path, zo_step):
     params = _params(2)
 
     def loss_fn(p, _):
@@ -125,7 +125,8 @@ def test_manager_restore_snapshot_plus_log(tmp_path):
     mgr = CheckpointManager(str(tmp_path), mezo_cfg=cfg, snapshot_every=3)
     p = jax.tree.map(jnp.copy, params)
     for t in range(7):
-        p, aux = mezo_step_vmapdir(loss_fn, p, None, jnp.uint32(t), cfg)
+        p, aux = zo_step(loss_fn, p, None, jnp.uint32(t), cfg,
+                         estimator="vmapdir")
         mgr.on_step(t, p, aux)
     # snapshot at 6 + log 0..6 -> restore resumes at 7
     restored, nxt = CheckpointManager(

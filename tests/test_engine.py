@@ -1,4 +1,4 @@
-"""Composable ZO engine: estimator×update registry matrix, TrainState
+"""ZO engine: the estimator×update matrix, the default step, TrainState
 checkpointing (momentum / Adam resume), straggler-mask renormalization
 across all combinations, replay parity, loss buffering, chunked stepping."""
 
@@ -11,17 +11,16 @@ import pytest
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
-from repro.core import (MezoConfig, build_strategy, estimator_names,
-                        fold_seed, get_strategy, mezo_step_vmapdir,
-                        replay_update, spsa_gradient_estimate,
-                        strategy_names, update_rule_names)
+from repro.core import (MezoConfig, add_scaled_z, build_strategy,
+                        estimator_names, fold_seed, replay_update,
+                        update_rule_names)
 from repro.core.engine import TrainState
 from repro.data.synthetic import lm_batches
 from repro.optim.adam import AdamConfig
 from repro.runtime import Trainer, TrainerConfig
 
-ALL_COMBOS = [(e, u) for e in ("walk", "vmapdir", "fused")
-              for u in ("sgd", "momentum")]
+ALL_COMBOS = [(e, u) for e in ("vmapdir", "fused")
+              for u in ("sgd", "momentum", "stale-sgd")]
 
 CFG = get_config("qwen3-4b").reduced()
 
@@ -53,24 +52,24 @@ def quad():
 
 
 def test_registry_names_and_errors():
-    assert set(estimator_names()) == {"walk", "vmapdir", "fused"}
+    assert set(estimator_names()) == {"vmapdir", "fused"}
     assert set(update_rule_names()) == {"sgd", "stale-sgd", "momentum"}
-    for name in strategy_names():
+    for est, upd in ALL_COMBOS:
         # cached singletons: jit caches keyed on the strategy stay warm
-        assert get_strategy(name) is get_strategy(name)
-    with pytest.raises(ValueError, match="mezo-fused"):
-        get_strategy("sgdm")
-    with pytest.raises(ValueError, match="vmapdir"):
-        build_strategy("vmap", "sgd")
+        assert build_strategy(est, upd) is build_strategy(est, upd)
+    for est in ("vmap", "walk"):
+        with pytest.raises(ValueError, match="fused") as ei:
+            build_strategy(est, "sgd")
+        assert "vmapdir" in str(ei.value)
     with pytest.raises(ValueError, match="momentum"):
-        build_strategy("walk", "adamw")
+        build_strategy("fused", "adamw")
 
 
 def test_unknown_trainer_optimizer_lists_strategies():
     with pytest.raises(ValueError) as ei:
         Trainer(CFG, TrainerConfig(optimizer="sgd"), iter(()))
     msg = str(ei.value)
-    assert "mezo-parallel" in msg and "mezo-fused" in msg and "adam" in msg
+    assert "'mezo'" in msg and "'adam'" in msg
 
 
 def test_cli_flags_reach_strategy():
@@ -81,12 +80,35 @@ def test_cli_flags_reach_strategy():
          "--seq", "8"])
     assert make_trainer(args).strategy.name == "fused+momentum"
     args = build_argparser().parse_args(
-        ["--arch", "opt-1.3b", "--reduced", "--optimizer", "mezo-momentum"])
-    assert make_trainer(args).strategy.name == "vmapdir+momentum"
+        ["--arch", "opt-1.3b", "--reduced", "--update", "momentum"])
+    assert make_trainer(args).strategy.name == "fused+momentum"
+    with pytest.raises(SystemExit):
+        build_argparser().parse_args(["--optimizer", "mezo-momentum"])
+
+
+def _default_trainer_strategy():
+    return Trainer(CFG, TrainerConfig(), iter(())).strategy
+
+
+def _default_cli_strategy():
+    from repro.launch.train import build_argparser, make_trainer
+    args = build_argparser().parse_args(
+        ["--arch", "opt-1.3b", "--reduced", "--steps", "2"])
+    return make_trainer(args).strategy
+
+
+@pytest.mark.parametrize("make", [_default_trainer_strategy,
+                                  _default_cli_strategy],
+                         ids=["trainer_config", "launch_train"])
+def test_default_zo_step_is_fused_sgd(make):
+    """With no estimator/update named, the ZO step is the one every
+    benchmark cell runs: the same cached strategy as
+    build_strategy("fused", "sgd")."""
+    assert make() is build_strategy("fused", "sgd")
 
 
 # ---------------------------------------------------------------------------
-# the full 3×2 matrix: constructible, descends, matches the SPSA estimate
+# the full 2×3 matrix: constructible, descends, matches the SPSA estimate
 
 
 @pytest.mark.parametrize("est,upd", ALL_COMBOS)
@@ -105,28 +127,61 @@ def test_matrix_constructible_and_descends(quad, est, upd):
     assert losses[-1] < 0.9 * losses[0]
 
 
+def _materialized_gs(loss_fn, params, batch, seed, cfg):
+    """The materialized SPSA estimate's projected gradients g_k, computed
+    eagerly on explicit theta ± eps*z_k copies, and the losses seen."""
+    eps = jnp.float32(cfg.eps)
+    gs, ls = [], []
+    for k in range(cfg.n_directions):
+        s = fold_seed(jnp.uint32(seed), jnp.uint32(k))
+        lp = loss_fn(add_scaled_z(params, s, eps, dist=cfg.dist), batch)
+        lm = loss_fn(add_scaled_z(params, s, -eps, dist=cfg.dist), batch)
+        gs.append(float((lp - lm) / (2.0 * eps)))
+        ls += [float(lp), float(lm)]
+    return np.asarray(gs, np.float32), np.asarray(ls, np.float32)
+
+
 @pytest.mark.parametrize("est,upd", ALL_COMBOS)
 def test_matrix_matches_spsa_estimate(quad, est, upd):
-    """One step of every combination equals theta - lr * w * g_spsa where
-    g_spsa is the materialized estimator cross-check (w = 1-beta for a
-    fresh momentum window, 1 for sgd)."""
+    """One step of every combination is theta - lr * w * mean_k g_k z_k
+    (w = 1-beta for a fresh momentum window, 1 for sgd), checked in two
+    parts:
+
+    * the update algebra, from the step's own g_k (``aux.gs``), at f32
+      tolerance;
+    * ``aux.gs`` against the materialized estimate's g_k. The jitted
+      step and the eager estimate reduce each loss in their own order.
+      A mean of N positive terms in f32 is within log2(N) * u * L of the
+      exact value (pairwise summation, u = 2^-24), so the two sides' l+
+      may differ by twice that, their l- too, and g_k = (l+ - l-) /
+      (2 eps) by 2 * 2 * log2(N) * u * L / (2 eps).
+    """
     params, batch, loss_fn = quad
     cfg = MezoConfig(eps=1e-3, lr=1e-2, n_directions=4, momentum=0.9,
                      momentum_window=4)
     strat = build_strategy(est, upd)
-    state, _ = strat.step(
+    state, aux = strat.step(
         loss_fn, strat.init_state(jax.tree.map(jnp.copy, params), cfg),
         batch, jnp.uint32(3), cfg)
-    g = spsa_gradient_estimate(loss_fn, params, batch, jnp.uint32(3), cfg)
+
     w = (1.0 - cfg.momentum) if upd == "momentum" else 1.0
-    want = jax.tree.map(lambda p, gg: p - cfg.lr * w * gg, params, g)
-    tol = (dict(rtol=1e-3, atol=1e-4) if est == "walk"     # walk drift
-           else dict(rtol=1e-5, atol=1e-6))
+    want = params
+    for k, g in enumerate(np.asarray(aux.gs)):
+        want = add_scaled_z(want, fold_seed(jnp.uint32(3), jnp.uint32(k)),
+                            -cfg.lr * w * g / cfg.n_directions)
     for a, b in zip(jax.tree.leaves(state.params), jax.tree.leaves(want)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-7)
+
+    g_mat, losses = _materialized_gs(loss_fn, params, batch, 3, cfg)
+    n = batch[1].size                      # terms in each loss's mean
+    u = np.finfo(np.float32).eps / 2
+    bound = 2 * 2 * np.log2(n) * u * np.abs(losses).max() / (2 * cfg.eps)
+    np.testing.assert_allclose(np.asarray(aux.gs), g_mat, rtol=0,
+                               atol=bound)
 
 
-def test_pristine_estimators_share_replay_log_bit_exact(quad):
+def test_estimators_share_replay_log_bit_exact(quad):
     """vmapdir and fused produce the same (seed, gs) record, and
     replay_update reconstructs each one's params bit-for-bit."""
     params, batch, loss_fn = quad
@@ -169,10 +224,9 @@ def test_direction_mask_unbiased_over_survivors(quad, est, upd):
     sb, _ = strat.step(
         loss_fn, strat.init_state(jax.tree.map(jnp.copy, params), cfg2),
         batch, jnp.uint32(5), cfg2)
-    tol = (dict(rtol=1e-3, atol=1e-4) if est == "walk"     # walk drift
-           else dict(rtol=1e-6, atol=1e-7))
     np.testing.assert_allclose(np.asarray(sa.params["w"]),
-                               np.asarray(sb.params["w"]), **tol)
+                               np.asarray(sb.params["w"]),
+                               rtol=1e-6, atol=1e-7)
 
 
 # ---------------------------------------------------------------------------
@@ -185,11 +239,13 @@ def test_weight_decay_replay_parity(quad):
     break bit-exactness."""
     params, batch, loss_fn = quad
     cfg = MezoConfig(eps=1e-3, lr=1e-2, n_directions=2, weight_decay=0.37)
-    p1, aux = mezo_step_vmapdir(loss_fn, jax.tree.map(jnp.copy, params),
-                                batch, jnp.uint32(9), cfg)
+    strat = build_strategy("vmapdir", "sgd")
+    s1, aux = strat.step(
+        loss_fn, strat.init_state(jax.tree.map(jnp.copy, params), cfg),
+        batch, jnp.uint32(9), cfg)
     p2 = replay_update(jax.tree.map(jnp.copy, params), aux.seed, aux.gs,
                        cfg)
-    for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
+    for a, b in zip(jax.tree.leaves(s1.params), jax.tree.leaves(p2)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -267,7 +323,7 @@ def test_restore_without_update_rule_refuses_stateful_opt(tmp_path, quad):
 
 def test_adam_rejects_estimator_update_flags():
     with pytest.raises(ValueError, match="adam"):
-        Trainer(CFG, TrainerConfig(optimizer="adam", estimator="fused"),
+        Trainer(CFG, TrainerConfig(optimizer="adam", estimator="vmapdir"),
                 iter(()))
 
 
@@ -279,12 +335,12 @@ def test_momentum_crash_resume_matches_uninterrupted(tmp_path):
     n = 12
     mz = MezoConfig(eps=1e-2, lr=1e-2, n_directions=2, momentum=0.9,
                     momentum_window=4)
-    tc_a = TrainerConfig(optimizer="mezo-momentum", mezo=mz, n_steps=n,
+    tc_a = TrainerConfig(update="momentum", mezo=mz, n_steps=n,
                          ckpt_dir=str(tmp_path / "a"), snapshot_every=4,
                          log_every=100)
     p_full = Trainer(CFG, tc_a, _batches()).train()
 
-    tc_b = TrainerConfig(optimizer="mezo-momentum", mezo=mz, n_steps=n,
+    tc_b = TrainerConfig(update="momentum", mezo=mz, n_steps=n,
                          ckpt_dir=str(tmp_path / "b"), snapshot_every=4,
                          log_every=100)
     with pytest.raises(RuntimeError):
@@ -293,9 +349,7 @@ def test_momentum_crash_resume_matches_uninterrupted(tmp_path):
     p_res = tr_c.train()
 
     for a, b in zip(jax.tree.leaves(p_full), jax.tree.leaves(p_res)):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   rtol=1e-4, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_adam_crash_resume_restores_moments(tmp_path):
@@ -331,7 +385,7 @@ def test_loss_history_identical_across_log_every():
                                           vocab=64)
 
     def run(log_every):
-        tc = TrainerConfig(optimizer="mezo-parallel",
+        tc = TrainerConfig(estimator="vmapdir",
                            mezo=MezoConfig(eps=1e-2, lr=1e-2,
                                            n_directions=2),
                            n_steps=7, log_every=log_every)
@@ -392,28 +446,3 @@ def test_run_chunk_resumes_step_counter(quad):
     for a, b in zip(jax.tree.leaves(s6.params),
                     jax.tree.leaves(s33.params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-# ---------------------------------------------------------------------------
-# back-compat: pre-engine momentum histories (no coeffs row) still step
-
-
-def test_legacy_momentum_history_upgrades():
-    from repro.core import mezo_momentum_step
-    params = {"w": jax.random.normal(jax.random.PRNGKey(2), (4, 4))}
-
-    def loss_fn(p, _):
-        return jnp.sum(p["w"] ** 2) * 1e-2
-
-    cfg = MezoConfig(eps=1e-3, lr=1e-2, n_directions=2, momentum=0.9,
-                     momentum_window=3)
-    old_hist = {"seeds": jnp.zeros((3,), jnp.uint32),
-                "gs": jnp.zeros((3, 2), jnp.float32)}
-    p, aux, hist = mezo_momentum_step(loss_fn, params, None, jnp.uint32(0),
-                                      cfg, old_hist)
-    assert set(hist) == {"seeds", "gs", "coeffs"}
-    assert np.isfinite(float(aux.loss))
-    # upgraded rows carry the -lr/K coefficient the old step applied
-    # (rows 0..1 are still the upgraded legacy entries after one roll)
-    np.testing.assert_allclose(np.asarray(hist["coeffs"][0]),
-                               -cfg.lr / cfg.n_directions, rtol=1e-6)

@@ -155,8 +155,8 @@ def test_coordinator_validates_config():
         FleetCoordinator(params,
                          MezoConfig(staleness_decay=0.0),
                          total_steps=1, n_workers=1)
-    with pytest.raises(ValueError, match="pristine"):
-        FleetSim(CFG, [WorkerSpec()], total_steps=1, estimator="walk")
+    with pytest.raises(ValueError, match="unknown direction estimator"):
+        FleetSim(CFG, [WorkerSpec()], total_steps=1, estimator="sequential")
     with pytest.raises(ValueError, match="unknown device grade"):
         get_grade("abacus")
     with pytest.raises(ValueError, match="never fire"):

@@ -1,5 +1,5 @@
 """Fused perturbed-forward path: kernel parity, ctx/salt consistency,
-mezo_step_fused equivalence with the sequential and vmapdir strategies."""
+the fused step's equivalence with the materialized vmapdir step."""
 
 import dataclasses
 
@@ -9,8 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core import (MezoConfig, PerturbCtx, add_scaled_z, mezo_step,
-                        mezo_step_fused, mezo_step_vmapdir, replay_update)
+from repro.core import MezoConfig, PerturbCtx, add_scaled_z, replay_update
 from repro.core import rng as zrng
 from repro.data.synthetic import lm_batches, sst2_batches
 from repro.kernels import ops, ref
@@ -138,13 +137,13 @@ def test_ctx_kernel_path_matches_jnp_path():
 # step-level equivalence
 
 
-def test_fused_step_matches_vmapdir_tight():
+def test_fused_step_matches_vmapdir_tight(zo_step):
     model, params, batch = _tiny_model()
     mcfg = MezoConfig(eps=1e-3, lr=1e-2, n_directions=3)
-    pf, auxf = mezo_step_fused(model.loss, jax.tree.map(jnp.copy, params),
-                               batch, jnp.uint32(7), mcfg)
-    pv, auxv = mezo_step_vmapdir(model.loss, jax.tree.map(jnp.copy, params),
-                                 batch, jnp.uint32(7), mcfg)
+    pf, auxf = zo_step(model.loss, jax.tree.map(jnp.copy, params), batch,
+                       jnp.uint32(7), mcfg)
+    pv, auxv = zo_step(model.loss, jax.tree.map(jnp.copy, params), batch,
+                       jnp.uint32(7), mcfg, estimator="vmapdir")
     np.testing.assert_allclose(np.asarray(auxf.gs), np.asarray(auxv.gs),
                                rtol=1e-6, atol=1e-7)
     for a, b in zip(jax.tree.leaves(pf), jax.tree.leaves(pv)):
@@ -152,48 +151,32 @@ def test_fused_step_matches_vmapdir_tight():
                                    rtol=1e-6, atol=1e-7)
 
 
-def test_fused_step_matches_sequential():
-    """Acceptance: fused params bit-comparable (f32 tol <= 1e-5) with the
-    sequential walk on a tiny transformer."""
-    model, params, batch = _tiny_model()
-    mcfg = MezoConfig(eps=1e-3, lr=1e-2, n_directions=3)
-    pf, auxf = mezo_step_fused(model.loss, jax.tree.map(jnp.copy, params),
-                               batch, jnp.uint32(7), mcfg)
-    ps, auxs = mezo_step(model.loss, jax.tree.map(jnp.copy, params),
-                         batch, jnp.uint32(7), mcfg)
-    np.testing.assert_allclose(float(auxf.loss), float(auxs.loss),
-                               rtol=1e-5, atol=1e-5)
-    for a, b in zip(jax.tree.leaves(pf), jax.tree.leaves(ps)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-5)
-
-
-def test_fused_step_replay_bit_exact():
-    """Fused updates apply to the pristine base point, so the (seed, gs)
+def test_fused_step_replay_bit_exact(zo_step):
+    """Fused updates apply to the unwritten base point, so the (seed, gs)
     replay log reconstructs them bit-for-bit (checkpointer contract)."""
     model, params, batch = _tiny_model()
     mcfg = MezoConfig(eps=1e-3, lr=1e-2, n_directions=2)
-    pf, aux = mezo_step_fused(model.loss, jax.tree.map(jnp.copy, params),
-                              batch, jnp.uint32(13), mcfg)
+    pf, aux = zo_step(model.loss, jax.tree.map(jnp.copy, params), batch,
+                      jnp.uint32(13), mcfg)
     pr = replay_update(jax.tree.map(jnp.copy, params), aux.seed, aux.gs, mcfg)
     for a, b in zip(jax.tree.leaves(pf), jax.tree.leaves(pr)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_fused_step_descends():
+def test_fused_step_descends(zo_step):
     model, params, batch = _tiny_model()
     mcfg = MezoConfig(eps=1e-2, lr=5e-3, n_directions=4)
     p = jax.tree.map(jnp.copy, params)
     losses = []
     for t in range(30):
-        p, aux = mezo_step_fused(model.loss, p, batch, jnp.uint32(t), mcfg)
+        p, aux = zo_step(model.loss, p, batch, jnp.uint32(t), mcfg)
         losses.append(float(aux.loss))
     assert losses[-1] < losses[0]
 
 
 @pytest.mark.parametrize("arch", ["rwkv6-7b", "jamba-v0.1-52b",
                                   "whisper-base"])
-def test_fused_step_matches_vmapdir_all_families(arch):
+def test_fused_step_matches_vmapdir_all_families(arch, zo_step):
     """The block-registry runtime threads PerturbCtx through every
     family, so the fused estimator's projected gradients match vmapdir's
     (which perturbs the whole tree) on hybrid / rwkv6 / encdec too --
@@ -207,9 +190,9 @@ def test_fused_step_matches_vmapdir_all_families(arch):
         batch["enc_embeds"] = jax.random.normal(
             jax.random.PRNGKey(3), (2, cfg.enc_len, cfg.d_model))
     mcfg = MezoConfig(eps=1e-3, lr=1e-2, n_directions=2)
-    pf, auxf = mezo_step_fused(model.loss, jax.tree.map(jnp.copy, params),
-                               batch, jnp.uint32(5), mcfg)
-    pv, auxv = mezo_step_vmapdir(model.loss, jax.tree.map(jnp.copy, params),
-                                 batch, jnp.uint32(5), mcfg)
+    pf, auxf = zo_step(model.loss, jax.tree.map(jnp.copy, params), batch,
+                       jnp.uint32(5), mcfg)
+    pv, auxv = zo_step(model.loss, jax.tree.map(jnp.copy, params), batch,
+                       jnp.uint32(5), mcfg, estimator="vmapdir")
     np.testing.assert_allclose(np.asarray(auxf.gs), np.asarray(auxv.gs),
                                rtol=1e-6, atol=1e-7)

@@ -113,16 +113,16 @@ def test_matmul_blocks_at_cell_shapes(m, k, n, xd, highest, want):
 
 def _grid(fn, *args):
     """The grid of the one pallas_call inside ``fn(*args)``."""
-    def walk(jaxpr):
+    def find(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
                 return eqn.params["grid_mapping"].grid
             for sub in jax.core.jaxprs_in_params(eqn.params):
-                got = walk(sub)
+                got = find(sub)
                 if got is not None:
                     return got
         return None
-    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return find(jax.make_jaxpr(fn)(*args).jaxpr)
 
 
 @pytest.mark.parametrize("xd,wd,scaled,precision", [

@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ALL_ARCHS, ARCHS, get_config
-from repro.core import MezoConfig, mezo_step
+from repro.core import MezoConfig
 from repro.models import build_model
 
 _FAM = os.environ.get("REPRO_FAMILY")
@@ -46,7 +46,7 @@ def make_batch(cfg, key):
 
 
 @pytest.mark.parametrize("arch", SMOKE_ARCHS)
-def test_smoke_forward_and_train_step(arch):
+def test_smoke_forward_and_train_step(arch, zo_step):
     cfg = get_config(arch).reduced()
     model = build_model(cfg)
     key = jax.random.PRNGKey(0)
@@ -63,8 +63,8 @@ def test_smoke_forward_and_train_step(arch):
     loss0 = float(model.loss(params, batch))
     assert np.isfinite(loss0)
 
-    p2, maux = mezo_step(model.loss, jax.tree.map(jnp.copy, params), batch,
-                         jnp.uint32(0), MezoConfig(eps=1e-3, lr=1e-4))
+    p2, maux = zo_step(model.loss, jax.tree.map(jnp.copy, params), batch,
+                       jnp.uint32(0), MezoConfig(eps=1e-3, lr=1e-4))
     assert np.isfinite(float(maux.loss))
     for leaf in jax.tree.leaves(p2):
         assert np.isfinite(np.asarray(leaf, np.float32)).all()

@@ -12,7 +12,7 @@ pytest.importorskip(
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import rng as zrng
-from repro.core.mezo import _direction_coeffs
+from repro.core.engine import _direction_coeffs
 from repro.models.sharding import fit_spec
 from repro.models.transformer import softmax_xent
 from repro.optim.compression import int8_dequantize, int8_quantize

@@ -10,7 +10,7 @@ import pytest
 
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
-from repro.core import MezoConfig, mezo_step_vmapdir
+from repro.core import MezoConfig
 from repro.launch.serve import serve
 from repro.models import build_model
 from repro.serve import AdapterStore, Request, ServeEngine, tree_bytes
@@ -137,10 +137,9 @@ def _tiny_params(key=0):
             "b": jnp.arange(5, dtype=jnp.float32)}
 
 
-def test_adapter_materialize_matches_checkpoint_restore(tmp_path):
+def test_adapter_materialize_matches_checkpoint_restore(tmp_path, zo_step):
     """AdapterStore.materialize (full-log replay from base) must be
-    bit-identical to CheckpointManager.restore (snapshot + tail replay)
-    for the pristine-base-point estimator."""
+    bit-identical to CheckpointManager.restore (snapshot + tail replay)."""
     params = _tiny_params(1)
 
     def loss_fn(p, _):
@@ -150,7 +149,8 @@ def test_adapter_materialize_matches_checkpoint_restore(tmp_path):
     mgr = CheckpointManager(str(tmp_path), mezo_cfg=cfg, snapshot_every=4)
     p = jax.tree.map(jnp.copy, params)
     for step in range(9):
-        p, aux = mezo_step_vmapdir(loss_fn, p, None, jnp.uint32(step), cfg)
+        p, aux = zo_step(loss_fn, p, None, jnp.uint32(step), cfg,
+                         estimator="vmapdir")
         mgr.on_step(step, p, aux)
 
     restored, nxt = CheckpointManager(str(tmp_path), mezo_cfg=cfg,

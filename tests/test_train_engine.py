@@ -309,11 +309,12 @@ def test_seed_collision_raises():
         eng.run()
 
 
-def test_walk_estimator_rejected():
+def test_unknown_estimator_rejected():
     cfg = _dense_cfg()
     store = AdapterStore(_fresh_base(cfg), mezo_cfg=MZ)
-    with pytest.raises(ValueError, match="pristine"):
-        TrainEngine(cfg, store, estimator="walk")
+    with pytest.raises(ValueError, match="unknown direction estimator") as ei:
+        TrainEngine(cfg, store, estimator="sequential")
+    assert "fused" in str(ei.value) and "vmapdir" in str(ei.value)
 
 
 def test_update_rule_mismatch_rejected():

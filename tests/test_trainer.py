@@ -38,9 +38,7 @@ def test_crash_resume_matches_uninterrupted(tmp_path):
     p_res = tr_c.train()
 
     for a, b in zip(jax.tree.leaves(p_full), jax.tree.leaves(p_res)):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   rtol=2e-2, atol=5e-5)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_adam_arm_descends():
@@ -65,7 +63,7 @@ def test_straggler_policy_masks():
 
 
 def test_straggler_trainer_arm():
-    tc = TrainerConfig(optimizer="mezo-parallel",
+    tc = TrainerConfig(estimator="vmapdir",
                        mezo=MezoConfig(eps=1e-2, lr=1e-2, n_directions=2),
                        n_steps=3, straggler_redundancy=2, log_every=100)
     tr = Trainer(CFG, tc, _batches())
@@ -91,7 +89,7 @@ def test_quantized_trainer_arm_runs_and_freezes_base():
     int8 values stay bit-frozen, the update stream lands in the deltas."""
     from repro.optim.quant import is_quantized, quantize_tree
 
-    tc = TrainerConfig(optimizer="mezo-fused", quant="int8",
+    tc = TrainerConfig(quant="int8",
                        mezo=MezoConfig(eps=1e-2, lr=1e-2, n_directions=2),
                        n_steps=3, log_every=100)
     tr = Trainer(CFG, tc, _batches())
