@@ -7,7 +7,9 @@ One process holds the chip and drives the real entry points in-process,
 on random weights made from seed 0:
 
   train    ``repro.launch.train``: the fused ZO step with the zo_matmul
-           kernel, 4 steps at batch 8 x seq 512, replay log in .smoke/
+           kernel, 4 steps at batch 8 x seq 512, replay log in .smoke/;
+           then both train cells' steps traced for the kernel's weight
+           reads, which must all come in place from the layer stacks
   serve    ``repro.launch.serve``: that replay log as one user's adapter,
            paged KV + chunked prefill + speculative decode, 8 requests
            over 4 slots, prompt 256, gen 32
@@ -78,6 +80,35 @@ def train_phase(ckpt_dir: str, *, reduced: bool = False, steps: int = 4,
         tr.tcfg.mezo).compile().as_text()
     return {"losses": tr.losses,
             "kernels": {"train_step": "tpu_custom_call" in text}}
+
+
+def step_weight_reads(arch: str, batch: int, seq: int) -> dict:
+    """zo_matmul weight reads, by path, counted while tracing the fused
+    step (``--use-kernel``) of ``arch`` at full widths on abstract
+    inputs: nothing is allocated or compiled."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import obs
+    from repro.configs import get_config
+    from repro.core.engine import MezoConfig, build_strategy
+    from repro.models import build_model
+
+    cfg = get_config(arch)
+    model = build_model(cfg)
+    strategy = build_strategy("fused", "sgd")
+    mezo = MezoConfig(use_kernel=True)
+    state = jax.eval_shape(lambda: strategy.init_state(
+        model.init(jax.random.PRNGKey(0)), mezo))
+    rows = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    labels = {"label": jax.ShapeDtypeStruct((batch,), jnp.int32)} \
+        if cfg.n_classes else {"targets": rows}
+    before = obs.zo_matmul_weights()
+    strategy.lower(lambda *a, **k: model.loss(*a, **k), state,
+                   {"tokens": rows, **labels}, jnp.uint32(0), mezo)
+    after = obs.zo_matmul_weights()
+    return {k: n - before.get(k, 0) for k, n in after.items()
+            if n != before.get(k, 0)}
 
 
 def serve_phase(ckpt_dir: str, *, reduced: bool = False, requests: int = 8,
@@ -282,8 +313,17 @@ def main() -> int:
 
     t = timed("train", train_phase, ckpt)
     from repro import obs
+    # both train cells' steps: two perturbed forwards, each reading its
+    # 6 projections per layer body in place from the stacks; the heads
+    # are unaligned and take the jnp path
+    reads = {arch: step_weight_reads(arch, b, s) for arch, b, s in
+             (("opt-1.3b", 8, 512), ("roberta-large", 64, 128))}
     log(f"train: losses {t['losses']} | attention cores traced, by path: "
-        f"{obs.attention_cores()}")
+        f"{obs.attention_cores()} | zo_matmul weight reads traced per "
+        f"step, by path: {reads}")
+    if any(r != {obs.W_STACKED: 12} for r in reads.values()):
+        raise AssertionError(f"train: weight reads {reads}, want "
+                             f"{{{obs.W_STACKED!r}: 12}} per step")
     s = timed("serve", serve_phase, ckpt)
     log(f"serve: {len(s['completions'])} requests | engine prefill "
         f"{s['prefill_s']!r}s, decode {s['decode_s']!r}s (first calls "

@@ -31,6 +31,11 @@ base (``rng.leaf_base`` / ``rng.fold_leading``) with ``prime_offset=1``.
 So for every leaf the fused forward sees *exactly* the z-field that
 ``add_scaled_z`` (and therefore ``spsa_gradient_estimate`` and the
 replay-log checkpointer) would apply to the stacked parameter tree.
+
+Stacked weights the kernel can read in place (:meth:`PerturbCtx.in_place`)
+reach a layer's body as a :class:`LayerSlice`, not as a slice: the
+kernel fetches the layer's tiles from the stack, and every other use
+slices the view first.
 """
 
 from __future__ import annotations
@@ -47,6 +52,28 @@ from repro.core.perturb import _path_str, is_perturbable, kernel_aligned
 from repro.optim.quant import is_quantized, take_rows, take_rows_f32
 
 PyTree = Any
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSlice:
+    """Layer ``layer`` of a scan-stacked weight, left unsliced in its
+    ``stack`` (an (L, K, N) array, or a quantized leaf of one).
+
+    :meth:`PerturbCtx.matmul` hands the fused kernel the stack and the
+    index; every other use takes :meth:`get`, the same 2-D leaf a scan
+    over the stack would have given."""
+    stack: Any
+    layer: Any
+
+    def get(self):
+        return jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, self.layer, 0, False),
+            self.stack)
+
+
+def _resolve(leaf):
+    """A :class:`LayerSlice`'s 2-D leaf; any other leaf as it is."""
+    return leaf.get() if isinstance(leaf, LayerSlice) else leaf
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,6 +128,20 @@ class PerturbCtx:
     def _coeff(self):
         return jnp.asarray(self.coeff, jnp.float32)
 
+    def _kernel_takes(self, w, shape) -> bool:
+        """Whether the fused kernel computes the matmul with ``w``, whose
+        (K, N) is ``shape``: the kernel path is on, and ``w`` is floating
+        (or a delta-less quantized base) and kernel-aligned."""
+        return self.use_kernel and kernel_aligned(shape) and \
+            jnp.issubdtype(w.dtype, jnp.floating) and \
+            not (is_quantized(w) and w.delta is not None)
+
+    def in_place(self, leaf) -> bool:
+        """Whether the fused kernel reads this scan-stacked (L, K, N) leaf
+        in place, so a layer scan hands it on as a :class:`LayerSlice`."""
+        return not self.batched and leaf.ndim == 3 and \
+            self._kernel_takes(leaf, leaf.shape[1:])
+
     # -- user axis ---------------------------------------------------------
 
     @property
@@ -141,6 +182,7 @@ class PerturbCtx:
 
         User-axis mode: ``leaf`` is per-user stacked (quantized: shared
         base, stacked delta); each lane gets its own z-field."""
+        leaf = _resolve(leaf)
         if self.batched:
             seeds, coeffs = self._user_lanes()
             return jax.vmap(
@@ -157,14 +199,17 @@ class PerturbCtx:
         return (lf + self._coeff() * z).astype(leaf.dtype)
 
     def matmul(self, x, w, name: str = "w"):
-        """x @ (w + coeff*z) for x (..., K), w (K, N).
+        """x @ (w + coeff*z) for x (..., K), w (K, N) or a
+        :class:`LayerSlice` of an (L, K, N) stack.
 
-        MXU-aligned 2-D weights go through the fused Pallas kernel (z never
-        leaves VMEM); everything else falls back to a transient jnp
-        materialization with identical values (ref.zo_matmul_ref semantics,
-        cast back to the weight dtype like ``add_scaled_z`` so the f32 path
-        is bit-exact with the sequential strategies). Either way the ops
-        run under the device scope ``zo_matmul.<projection path>``.
+        MXU-aligned weights go through the fused Pallas kernel (z never
+        leaves VMEM), a LayerSlice as its whole stack plus the layer index
+        (the kernel reads the layer's tiles in place); everything else
+        falls back to a transient jnp materialization with identical
+        values (ref.zo_matmul_ref semantics, cast back to the weight dtype
+        like ``add_scaled_z`` so the f32 path is bit-exact with the
+        sequential strategies). Either way the ops run under the device
+        scope ``zo_matmul.<projection path>``.
         """
         with jax.named_scope(obs.MATMUL + (self.prefix or name)):
             if self.batched:
@@ -173,27 +218,27 @@ class PerturbCtx:
 
     def _matmul_one(self, x, w, name: str):
         path, base, off = self._leaf(name)
+        if isinstance(w, LayerSlice) and is_perturbable(path):
+            return self._kernel(x, w.stack, base, off, w.layer)
+        w = _resolve(w)
         if not is_perturbable(path) or \
                 not jnp.issubdtype(w.dtype, jnp.floating):
             return x @ (w.dequantize() if is_quantized(w) else w)
-        k, n = w.shape
-        if self.use_kernel and kernel_aligned(w.shape) and \
-                not (is_quantized(w) and w.delta is not None):
-            from repro.kernels import ops as kops  # lazy: pallas import
-            lead = x.shape[:-1]
-            if is_quantized(w):
-                # dequant fused into the same kernel tile pass:
-                # X @ (q*scale + coeff*z), base resident as int8
-                y = kops.zo_matmul(x.reshape(-1, k), w.q, base, 0,
-                                   self._coeff(), dist=self.dist,
-                                   prime_offset=off, prehashed=True,
-                                   scale=w.scale)
-            else:
-                y = kops.zo_matmul(x.reshape(-1, k), w, base, 0,
-                                   self._coeff(), dist=self.dist,
-                                   prime_offset=off, prehashed=True)
-            return y.reshape(*lead, n)
+        if self._kernel_takes(w, w.shape):
+            return self._kernel(x, w, base, off)
         return x @ self.perturb(name, w)
+
+    def _kernel(self, x, w, base, off, layer=None):
+        """The fused kernel on a 2-D weight, or on a stack at ``layer``; a
+        quantized base dequantizes in the same tile pass (X @ (q*scale +
+        coeff*z), base resident as int8)."""
+        from repro.kernels import ops as kops  # lazy: pallas import
+        obs.zo_matmul_weight(obs.W_SLICE if layer is None else obs.W_STACKED)
+        q, scale = (w.q, w.scale) if is_quantized(w) else (w, None)
+        y = kops.zo_matmul(x.reshape(-1, x.shape[-1]), q, base, 0,
+                           self._coeff(), dist=self.dist, prime_offset=off,
+                           prehashed=True, scale=scale, layer=layer)
+        return y.reshape(*x.shape[:-1], y.shape[-1])
 
     def _matmul_users(self, x, w, name: str):
         """User-axis matmul: x (U, ..., K). A SHARED 2-D base (plain f32
@@ -236,6 +281,7 @@ class PerturbCtx:
 
         User-axis mode: ``ids`` carry a leading user axis; the table
         follows the weight conventions (stacked plain / shared base)."""
+        table = _resolve(table)
         if self.batched:
             seeds, coeffs = self._user_lanes()
             return jax.vmap(

@@ -76,10 +76,12 @@ def zo_add(w, seed, salt: int, coeff, dist: str = "rademacher",
 
 def zo_matmul(x, w, seed, salt: int, coeff, dist: str = "rademacher",
               blocks=None, prime_offset: int = 0,
-              prehashed: bool = False, scale=None):
+              prehashed: bool = False, scale=None, layer=None):
+    """``x @ (w + coeff*z)``; a stacked (L, K, N) ``w`` is read in place
+    at ``layer``."""
     return _k.zo_matmul(x, w, seed, salt, coeff, dist=dist, blocks=blocks,
                         interpret=_INTERPRET, prime_offset=prime_offset,
-                        prehashed=prehashed, scale=scale)
+                        prehashed=prehashed, scale=scale, layer=layer)
 
 
 def zo_add_users(w, seeds, salt: int, coeffs, dist: str = "rademacher",

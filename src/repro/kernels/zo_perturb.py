@@ -10,7 +10,8 @@ kernel so z never exists in HBM at all:
   * ``zo_matmul_kernel``  -- Y  = X @ (W + coeff * z(seed))  (perturbed
                              forward matmul: the perturbation is fused
                              into the MXU pipeline; W is read once and z
-                             costs zero HBM bytes)
+                             costs zero HBM bytes; W may be one layer of
+                             a scan-stacked (L, K, N) leaf, read in place)
 
 Both kernels also take an optional per-output-channel ``scale`` vector
 marking W as an *int8 quantized base* (optim/quant.py): the tile is then
@@ -326,76 +327,79 @@ def _zo_matmul_q_kernel(seed_ref, coeff_ref, x_ref, w_ref, s_ref, o_ref,
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
+def _after_prefetch(kernel):
+    """``kernel`` with the scalar-prefetch layer ref dropped: only the
+    index maps read it."""
+    return lambda layer_ref, *refs: kernel(*refs)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("salt", "dist", "blocks", "interpret",
                                     "prime_offset", "prehashed"))
 def zo_matmul(x, w, seed, salt: int, coeff, dist: str = "rademacher",
               blocks=None, interpret: bool = False,
-              prime_offset: int = 0, prehashed: bool = False, scale=None):
-    """Y = X @ (W + coeff * z(seed)). X: (M, K), W: (K, N).
+              prime_offset: int = 0, prehashed: bool = False, scale=None,
+              layer=None):
+    """Y = X @ (W + coeff * z(seed)). X: (M, K), W: (K, N), or a
+    layer-stacked W: (L, K, N) read at ``layer``.
 
     The perturbed weight tile lives only in VMEM: HBM traffic is exactly
     the unperturbed matmul's (X, W read once; Y written once).
 
-    scale: per-output-channel (N,) f32 scales marking ``w`` as an int8
-    quantized base -- the kernel then computes
+    A stacked W is never sliced: ``layer`` rides SMEM as a scalar-prefetch
+    operand and the weight's BlockSpec fetches that layer's tiles from
+    the stack in place; a 2-D W is layer 0 of a stack of one. Tiles, z
+    and accumulation order are those of the 2-D call on ``w[layer]``, so
+    the output is bit-identical to it; the z-field of the layer comes, as
+    there, from a ``seed`` with the layer folded in (``prehashed``).
+
+    scale: per-output-channel f32 scales, (N,) -- or (L, N) for a stacked
+    W -- marking ``w`` as an int8 quantized base; the kernel then computes
     ``X @ (w*scale + coeff*z)`` with dequantization fused into the same
     VMEM tile pass (weight HBM reads ~1/4 of the f32 kernel's, z still
     zero bytes; the prehashed-salt scheme is untouched).
 
     prehashed/prime_offset: see :func:`_tile_z` -- lets the kernel compute
-    the perturbed forward for one layer-slice of a scan-stacked (L, K, N)
-    leaf while staying bit-exact with the full-leaf reference field.
+    the perturbed forward for one layer of a scan-stacked (L, K, N) leaf
+    while staying bit-exact with the full-leaf reference field.
 
     blocks: None picks (bm, bk, bn) from the shapes, the dtypes and the
     dot's precision (:func:`matmul_blocks`); a tuple pins the tiling.
     """
+    if w.ndim == 2:                       # layer 0 of a stack of one
+        w, layer = w[None], 0
     m, k = x.shape
-    k2, n = w.shape
+    _, k2, n = w.shape
     assert k == k2
     bm, bk, bn = _matmul_tiling(blocks, m, k, n, x.dtype, w.dtype,
                                 scale is not None)
     grid = (m // bm, n // bn, k // bk)
     seed = jnp.asarray(seed, _U32).reshape(1)
     coeff = jnp.asarray(coeff, jnp.float32).reshape(1)
-    if scale is None:
-        kern = functools.partial(_zo_matmul_kernel, salt=salt, bk=bk, bn=bn,
-                                 n_k=grid[2], dist=dist,
-                                 prime_offset=prime_offset,
-                                 prehashed=prehashed)
-        return pl.pallas_call(
-            kern,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-                pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            ],
-            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-            interpret=interpret,
-        )(seed, coeff, x, w)
-    scale = jnp.asarray(scale, jnp.float32).reshape(1, n)
-    kern = functools.partial(_zo_matmul_q_kernel, salt=salt, bk=bk, bn=bn,
-                             n_k=grid[2], dist=dist,
-                             prime_offset=prime_offset, prehashed=prehashed)
+    kern = functools.partial(
+        _zo_matmul_kernel if scale is None else _zo_matmul_q_kernel,
+        salt=salt, bk=bk, bn=bn, n_k=grid[2], dist=dist,
+        prime_offset=prime_offset, prehashed=prehashed)
+    # index maps take (i, j, kk) and the layer ref
+    in_specs = [
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+        pl.BlockSpec((bm, bk), lambda i, j, kk, l: (i, kk)),
+        pl.BlockSpec((None, bk, bn), lambda i, j, kk, l: (l[0], kk, j)),
+    ]
+    args = [seed, coeff, x, w]
+    if scale is not None:
+        in_specs.append(
+            pl.BlockSpec((None, 1, bn), lambda i, j, kk, l: (l[0], 0, j)))
+        args.append(jnp.asarray(scale, jnp.float32).reshape(-1, 1, n))
     return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret,
-    )(seed, coeff, x, w, scale)
+        _after_prefetch(kern),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, l: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype), interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), *args)
 
 
 # ---------------------------------------------------------------------------

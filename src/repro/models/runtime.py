@@ -42,10 +42,12 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.core.perturb_ctx import LayerSlice
 from repro.core.perturb_ctx import sub as _sub
 from repro.models import layers as L
 from repro.models.blocks import RunCtx, get_block
 from repro.models.config import ModelConfig
+from repro.optim.quant import is_quantized
 
 PyTree = Any
 AUX_LOSS_WEIGHT = 0.01
@@ -130,12 +132,19 @@ def _decode_positions(pos):
 def _stack_apply(cfg, stack: StackPlan, params, x, rc: RunCtx, ctx):
     """Full-sequence stack: scan over stacked layer params. The perturb
     ctx binds the scan index (``at_layer``) so per-layer z slices match
-    each stacked leaf's field."""
-    blocks_p = params[stack.scope]
+    each stacked leaf's field. Leaves the fused kernel reads in place
+    (``ctx.in_place``) are not scanned: the body gets each as a
+    :class:`LayerSlice` of the whole stack."""
+    leaves, treedef = jax.tree_util.tree_flatten(params[stack.scope],
+                                                 is_leaf=is_quantized)
     sctx = None if ctx is None else ctx.scope(stack.scope)
+    in_place = [sctx is not None and sctx.in_place(lf) for lf in leaves]
 
     def body(carry, xs):
-        bp, li = xs
+        sliced, li = xs
+        sliced = iter(sliced)
+        bp = treedef.unflatten([LayerSlice(lf, li) if keep else next(sliced)
+                                for lf, keep in zip(leaves, in_place)])
         h, aux = carry
         bctx = None if sctx is None else sctx.at_layer(li)
         for sl in stack.sublayers:
@@ -147,10 +156,11 @@ def _stack_apply(cfg, stack: StackPlan, params, x, rc: RunCtx, ctx):
             aux = aux + a
         return (h, aux), None
 
-    n_layers = jax.tree_util.tree_leaves(blocks_p)[0].shape[0]
+    n_layers = jax.tree_util.tree_leaves(leaves)[0].shape[0]
     (x, aux), _ = jax.lax.scan(
         body, (x, jnp.float32(0.0)),
-        (blocks_p, jnp.arange(n_layers, dtype=jnp.uint32)))
+        ([lf for lf, keep in zip(leaves, in_place) if not keep],
+         jnp.arange(n_layers, dtype=jnp.uint32)))
     return x, aux
 
 

@@ -1,5 +1,6 @@
 """The program's tracing layer: host spans, device scopes, counts of
-compiles and of the attention core's paths.
+compiles, of the attention core's paths and of the fused matmul's
+weight reads.
 
 Spans are ``jax.profiler.TraceAnnotation``s named ``repro.<name>``: they
 sit on the profiler's clock beside the device trace, and exist only
@@ -31,6 +32,10 @@ MATMUL = "zo_matmul."            # + a projection's parameter path
 # attention-core paths, counted as traced
 ATTN_KERNEL = "flash_attention"  # the Pallas kernel
 ATTN_JNP = "attention"           # the jnp reference path
+
+# how the fused ZO matmul kernel gets its weight, counted as traced
+W_STACKED = "stacked"            # tiles read in place from an (L, K, N) stack
+W_SLICE = "slice"                # a 2-D (K, N) weight
 
 # host spans
 PREFIX = "repro."
@@ -106,3 +111,19 @@ def attention_core(path: str) -> None:
 def attention_cores() -> Dict[str, int]:
     """Attention cores traced since import, per path."""
     return dict(_attention_cores)
+
+
+# fused-matmul weight reads, by how the kernel gets the weight
+_zo_matmul_weights: Dict[str, int] = collections.Counter()
+
+
+def zo_matmul_weight(path: str) -> None:
+    """Count one ``zo_matmul`` call traced with its weight on ``path``
+    (W_STACKED or W_SLICE); a layer scan traces its body, and so counts,
+    once."""
+    _zo_matmul_weights[path] += 1
+
+
+def zo_matmul_weights() -> Dict[str, int]:
+    """``zo_matmul`` weight reads traced since import, per path."""
+    return dict(_zo_matmul_weights)

@@ -1,7 +1,9 @@
 """Every main-path Pallas kernel compiles for a described TPU v5e at
 opt-1.3b widths (d_model 2048, d_ff 8192, 32 heads of 64, pages of 16),
 and the fused ZO matmul and the attention core also at roberta-large's
-f32 widths.
+f32 widths; the fused ZO matmul also on a layer-stacked weight, and the
+fused step of a 2-layer model at roberta-large widths hands it the stack
+without a slice copy.
 
 Nothing runs: the TPU compiler, installed here, compiles for a chip that
 is described and not attached, and refuses what the chip would refuse
@@ -12,18 +14,22 @@ process may load the TPU library at a time, and pytest-xdist workers
 import every test file.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import obs
 from repro.kernels import zo_perturb as zp
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.flash_verify import flash_verify
 
+NL = 24                              # layers of both train models
 D, F = 2048, 8192                    # opt-1.3b d_model, d_ff
 RD, RF = 1024, 4096                  # roberta-large d_model, d_ff
 H, KV, HD, PS = 32, 32, 64, 16       # heads, kv heads, head_dim, page size
@@ -62,6 +68,19 @@ def _zo_matmul_highest(x, w, s, c):
 
 def _zo_matmul_q(x, w, sc, s, c):
     return zp.zo_matmul(x, w, s, 0, c, scale=sc)
+
+
+def _zo_matmul_stacked(x, w, s, c, layer):
+    return zp.zo_matmul(x, w, s, 0, c, layer=layer)
+
+
+def _zo_matmul_stacked_highest(x, w, s, c, layer):
+    with jax.default_matmul_precision("highest"):
+        return zp.zo_matmul(x, w, s, 0, c, layer=layer)
+
+
+def _zo_matmul_stacked_q(x, w, sc, s, c, layer):
+    return zp.zo_matmul(x, w, s, 0, c, scale=sc, layer=layer)
 
 
 def _zo_matmul_users_q(x, w, sc, s, c):
@@ -118,6 +137,16 @@ CASES = {
         ((8192, RF), F32), ((RF, RD), F32), ((), U32), ((), F32)]),
     "zo_matmul_int8": (_zo_matmul_q, [((512, D), BF), ((D, F), I8),
                                       ((F,), F32), ((), U32), ((), F32)]),
+    # a layer of the (24, K, N) stack, read in place: opt-1.3b's w_in in
+    # bf16, roberta-large's w_out in f32 at HIGHEST, an int8 stack
+    "zo_matmul_bf16_stacked_w_in": (_zo_matmul_stacked, [
+        ((4096, D), BF), ((NL, D, F), BF), ((), U32), ((), F32), ((), U32)]),
+    "zo_matmul_f32_highest_rl_stacked_w_out": (_zo_matmul_stacked_highest, [
+        ((8192, RF), F32), ((NL, RF, RD), F32), ((), U32), ((), F32),
+        ((), U32)]),
+    "zo_matmul_int8_stacked": (_zo_matmul_stacked_q, [
+        ((512, D), BF), ((NL, D, F), I8), ((NL, F), F32), ((), U32),
+        ((), F32), ((), U32)]),
     "zo_matmul_users_int8": (_zo_matmul_users_q, [
         ((4, 512, D), BF), ((D, F), I8), ((F,), F32), ((4,), U32),
         ((4,), F32)]),
@@ -147,3 +176,70 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in specs]
     compiled = jax.jit(kernel).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _zo_matmul_weight_producers(text):
+    """For each fused-matmul custom call of an optimized HLO text: the
+    (opcode, shape, fused computation's text) that produce its
+    operands."""
+    insts, comps, comp = {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) .*\{$", line)
+        if head:
+            comp = comps.setdefault(head.group(1), [])
+        elif line == "}":
+            comp = None
+        elif comp is not None:
+            comp.append(line)
+            inst = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ([a-z][\w\-]*)"
+                            r"\((.*)", line)
+            if inst:
+                insts[inst.group(1)] = inst.groups()[1:]
+    calls = []
+    for shape, opcode, rest in insts.values():
+        if opcode == "custom-call" and "tpu_custom_call" in rest and \
+                f"/{obs.MATMUL}" in rest:
+            producers = []
+            for operand in re.findall(r"%[\w.\-]+", rest.split(")")[0]):
+                shp, op, body = insts[operand]
+                called = re.search(r"calls=(%[\w.\-]+)", body)
+                producers.append((op, shp, "\n".join(
+                    comps.get(called.group(1), ())) if called else ""))
+            calls.append(producers)
+    return calls
+
+
+def test_fused_step_reads_weights_from_the_stack(one_chip, monkeypatch):
+    """The fused step of a 2-layer model at roberta-large widths (f32,
+    HIGHEST), compiled for the chip: every fused-matmul call takes a
+    (L, K, N) stack as an operand and none takes an operand made by a
+    dynamic-slice -- the per-layer weight copy out of the stack is gone."""
+    from repro.configs import get_config
+    from repro.core.engine import MezoConfig, build_strategy
+    from repro.kernels import ops
+    from repro.models import build_model
+
+    monkeypatch.setattr(ops, "_INTERPRET", False)
+    cfg = dataclasses.replace(get_config("roberta-large"), n_layers=2,
+                              vocab=512)
+    model = build_model(cfg)
+    strategy = build_strategy("fused", "sgd")
+    mezo = MezoConfig(lr=1e-6, eps=1e-3, use_kernel=True)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = jax.tree.map(lambda a: on_chip(a.shape, a.dtype), jax.eval_shape(
+        lambda: strategy.init_state(model.init(jax.random.PRNGKey(0)),
+                                    mezo)))
+    batch = {"tokens": on_chip((8, 128), I32), "label": on_chip((8,), I32)}
+    with jax.default_matmul_precision("highest"):
+        text = strategy.lower(model.loss, state, batch, jnp.uint32(1),
+                              mezo).compile().as_text()
+    calls = _zo_matmul_weight_producers(text)
+    assert len(calls) == 12                  # 6 projections x 2 forwards
+    for producers in calls:
+        assert any(shape.split("]")[0].count(",") == 2
+                   for _, shape, _ in producers)
+        assert not any(op == "dynamic-slice" or " dynamic-slice(" in fused
+                       for op, _, fused in producers)

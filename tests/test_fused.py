@@ -8,12 +8,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import get_config
 from repro.core import MezoConfig, PerturbCtx, add_scaled_z, replay_update
 from repro.core import rng as zrng
 from repro.data.synthetic import lm_batches, sst2_batches
 from repro.kernels import ops, ref
 from repro.models import build_model
+from repro.optim.quant import quantize_tree
 
 KEY = jax.random.PRNGKey(0)
 
@@ -131,6 +133,83 @@ def test_ctx_kernel_path_matches_jnp_path():
     lk = float(model.loss(params, batch,
                           perturb=dataclasses.replace(ctx, use_kernel=True)))
     np.testing.assert_allclose(lj, lk, rtol=1e-5, atol=1e-6)
+
+
+# the two train cells' kinds of model, at aligned widths: opt-like in bf16
+# with ReLU and an aligned 2-D LM head; roberta-like in f32 with GELU at
+# HIGHEST and an unaligned 2-class head; and opt-like on a frozen int8
+# base. name -> (arch, overrides, precision, aligned heads, int8 base)
+STACKED_MODELS = {
+    "opt-bf16-relu": ("opt-1.3b", dict(dtype="bfloat16", vocab=256), None, 1,
+                      False),
+    "roberta-f32-gelu-highest": ("roberta-large", dict(vocab=200),
+                                 "highest", 0, False),
+    "opt-int8-base": ("opt-1.3b", dict(vocab=256), None, 1, True),
+}
+
+
+def _aligned_model(arch, **overrides):
+    kw = dict(n_layers=3, d_model=128, n_heads=2, n_kv_heads=2, d_ff=256)
+    kw.update(overrides)
+    cfg = get_config(arch).reduced(**kw)
+    model = build_model(cfg)
+    gen = sst2_batches if cfg.n_classes else lm_batches
+    batch = {k: jnp.asarray(v)
+             for k, v in next(gen(2, 16, cfg.vocab, seed=1)).items()}
+    return model, model.init(jax.random.PRNGKey(0)), batch
+
+
+def _traced_loss(model, params, batch, ctx, precision=None):
+    """(loss, zo_matmul weight reads counted while tracing it) of one
+    freshly traced perturbed forward."""
+    before = obs.zo_matmul_weights()
+    with jax.default_matmul_precision(precision):
+        loss = jax.jit(lambda p, b: model.loss(p, b, perturb=ctx))(params,
+                                                                   batch)
+    after = obs.zo_matmul_weights()
+    return np.asarray(loss), {k: after[k] - before.get(k, 0) for k in after
+                              if after[k] != before.get(k, 0)}
+
+
+@pytest.mark.parametrize("name", sorted(STACKED_MODELS))
+def test_stacked_weights_read_in_place_match_the_sliced_path(name,
+                                                             monkeypatch):
+    """The layer scan hands the fused kernel each projection's whole
+    stack (6 reads in place per layer body, none sliced); the loss is
+    bit-identical to scanning the stacks and passing 2-D slices."""
+    arch, overrides, precision, head, int8 = STACKED_MODELS[name]
+    model, params, batch = _aligned_model(arch, **overrides)
+    if int8:
+        params = quantize_tree(params)
+    ctx = PerturbCtx(seed=jnp.uint32(3), coeff=jnp.float32(1e-3),
+                     use_kernel=True)
+    got, reads = _traced_loss(model, params, batch, ctx, precision)
+    assert reads == {obs.W_STACKED: 6, **({obs.W_SLICE: head} if head
+                                          else {})}
+    monkeypatch.setattr(PerturbCtx, "in_place", lambda self, leaf: False)
+    want, sliced = _traced_loss(model, params, batch, ctx, precision)
+    assert sliced == {obs.W_SLICE: 6 + head}
+    assert got.tobytes() == want.tobytes()
+
+
+def test_plain_and_unaligned_forwards_keep_their_path():
+    """The plain forward and a model whose widths the kernel cannot tile
+    count no kernel weight reads, and give the values of the jnp path."""
+    model, params, batch = _aligned_model("opt-1.3b", d_model=64, d_ff=96,
+                                          vocab=100)
+    ctx = PerturbCtx(seed=jnp.uint32(3), coeff=jnp.float32(1e-3))
+    fallback, reads = _traced_loss(
+        model, params, batch, dataclasses.replace(ctx, use_kernel=True))
+    assert reads == {}
+    assert fallback.tobytes() == _traced_loss(model, params, batch,
+                                              ctx)[0].tobytes()
+    model, params, batch = _aligned_model("opt-1.3b")
+    before = obs.zo_matmul_weights()
+    plain = jax.jit(model.loss)(params, batch)
+    assert obs.zo_matmul_weights() == before
+    at_zero = _traced_loss(model, params, batch,
+                           dataclasses.replace(ctx, coeff=jnp.float32(0)))[0]
+    assert np.asarray(plain).tobytes() == at_zero.tobytes()
 
 
 # ---------------------------------------------------------------------------

@@ -147,6 +147,39 @@ def test_zo_matmul_users_picks_scalar_blocks(xd, wd, scaled, precision):
     assert many == (u,) + one
 
 
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("dist", ["rademacher", "gaussian"])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_zo_matmul_stacked_bit_equals_layer_slice(dtype, layer, dist,
+                                                  quantized):
+    """A layer-stacked (L, K, N) weight read in place at ``layer`` gives
+    the 2-D call on ``w[layer]`` bit for bit: same tiles, same z, same
+    accumulation order. The seed is the layer-folded leaf base the fused
+    forward passes; a grid of 2 x 3 x 2 tiles walks every index map."""
+    from repro.core import rng as zrng
+    n_layers, m, k, n = 3, 16, 256, 384
+    x = (jax.random.normal(KEY, (m, k), jnp.float32) * 0.1).astype(dtype)
+    wkey = jax.random.fold_in(KEY, 9)
+    if quantized:
+        w = jax.random.randint(wkey, (n_layers, k, n), -127, 128, jnp.int8)
+        scale = jnp.abs(jax.random.normal(jax.random.fold_in(KEY, 10),
+                                          (n_layers, n))) * 0.01 + 1e-4
+        one_scale = scale[layer]
+    else:
+        w = (jax.random.normal(wkey, (n_layers, k, n)) * 0.1).astype(dtype)
+        scale = one_scale = None
+    base = zrng.fold_leading(zrng.leaf_base(jnp.uint32(21), 4242), layer)
+    kw = dict(dist=dist, blocks=(8, 128, 128), prime_offset=1,
+              prehashed=True)
+    got = ops.zo_matmul(x, w, base, 0, 1e-2, scale=scale,
+                        layer=jnp.uint32(layer), **kw)
+    want = ops.zo_matmul(x, w[layer], base, 0, 1e-2, scale=one_scale, **kw)
+    assert got.dtype == want.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(want.astype(jnp.float32)))
+
+
 def test_zero_coeff_is_identity_matmul():
     x = jax.random.normal(KEY, (64, 128), jnp.float32)
     w = jax.random.normal(jax.random.fold_in(KEY, 3), (128, 64), jnp.float32)

@@ -184,3 +184,38 @@ def test_attention_core_counter_counts_each_path_once_per_trace(
         after = obs.attention_cores()
         assert after.get(path, 0) - before.get(path, 0) == 1
         assert after.get(other, 0) == before.get(other, 0)
+
+
+# ---------------------------------------------------------------------------
+# the fused matmul's weight reads, in both train models at aligned widths
+
+ALIGNED = {"opt-1.3b": (dict(dtype="bfloat16", vocab=256),
+                        {"targets": jnp.zeros((2, 16), jnp.int32)}, 1),
+           "roberta-large": (dict(vocab=200),
+                             {"label": jnp.array([0, 1], jnp.int32)}, 0)}
+
+
+@pytest.mark.parametrize("arch", sorted(ALIGNED))
+def test_fused_step_reads_each_layer_weight_in_place(arch):
+    """Tracing the fused step counts, for each of its two perturbed
+    forwards, 6 projection weights read in place from their stacks and
+    none sliced out of them; only an aligned 2-D head counts ``slice``.
+    The plain forward counts nothing."""
+    overrides, labels, head = ALIGNED[arch]
+    from repro.configs import get_config
+    cfg = get_config(arch).reduced(n_layers=3, d_model=128, n_heads=2,
+                                   n_kv_heads=2, d_ff=256, **overrides)
+    model = build_model(cfg)
+    strategy = build_strategy("fused", "sgd")
+    mezo = MezoConfig(lr=1e-6, eps=1e-3, use_kernel=True)
+    params = model.init(jax.random.PRNGKey(0))
+    state = strategy.init_state(params, mezo)
+    batch = {"tokens": jnp.zeros((2, 16), jnp.int32), **labels}
+    before = obs.zo_matmul_weights()
+    strategy.lower(lambda *a, **k: model.loss(*a, **k), state, batch,
+                   jnp.uint32(1), mezo)
+    after = obs.zo_matmul_weights()
+    assert after.get(obs.W_STACKED, 0) - before.get(obs.W_STACKED, 0) == 12
+    assert after.get(obs.W_SLICE, 0) - before.get(obs.W_SLICE, 0) == 2 * head
+    jax.jit(lambda p, b: model.loss(p, b)).lower(params, batch)
+    assert obs.zo_matmul_weights() == after
